@@ -334,7 +334,7 @@ def test_jit_rule_covers_shard_map_bodies_and_partial_jit(tmp_path):
         from functools import partial
 
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build(mesh):
             def body(x):

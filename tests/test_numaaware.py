@@ -237,6 +237,26 @@ class TestNumaAwareIntegration:
         assert h.binds == {"ns1/p0": "n1"}
 
 
+    @pytest.mark.parametrize("policy,bound", [("", True),
+                                              ("single-numa-node", False)])
+    def test_no_numa_nodes_needs_no_host_sweep(self, policy, bound):
+        """Without NUMA topology on any node the plugin's predicate is a
+        solver mask: a Guaranteed task with a policy fits nowhere, any
+        other task is untouched, and the per-node host sweep never runs."""
+        from volcano_tpu.metrics import metrics as m
+        h = Harness(CONF)
+        h.add("queues", build_queue("default"))
+        h.add("nodes", build_node("n1", {"cpu": "8", "memory": "16Gi"}))
+        h.add("podgroups", build_pod_group("pg1", "ns1", "default", 1,
+                                           phase="Inqueue"))
+        h.add("pods", guaranteed_pod("ns1", "p0", "pg1", cpu="2",
+                                     policy=policy))
+        swept = m.counter_total(m.SOLVER_HOST_PREDICATE)
+        h.run_actions("allocate").close_session()
+        assert h.binds == ({"ns1/p0": "n1"} if bound else {})
+        assert m.counter_total(m.SOLVER_HOST_PREDICATE) == swept
+
+
 class TestGuaranteedQoS:
     def test_is_guaranteed(self):
         pod = guaranteed_pod("ns", "p", "g")

@@ -6,6 +6,9 @@ the scheduler's own hot path, not just the bench harness.
 Reference hot path: pkg/scheduler/actions/allocate/allocate.go:201-262.
 """
 
+import numpy as np
+import pytest
+
 from tests.harness import Harness
 from volcano_tpu.utils.test_utils import (build_node, build_pod,
                                           build_pod_group, build_queue,
@@ -54,6 +57,44 @@ def test_pallas_kernel_conf_selected():
     fn, kwargs = ssn.solver._select_kernel()
     assert fn.__name__ == "gang_allocate_pallas"
     assert kwargs.get("interpret") is True  # CPU backend in tests
+    h.close_session()
+
+
+@pytest.mark.parametrize("conf,n_tasks,kernel", [
+    (CONF_SCAN, 50_176, "gang_allocate_pallas"),       # auto, fits SMEM
+    (CONF_SCAN, 500_736, "gang_allocate"),             # auto, too large
+    (CONF_PALLAS, 500_736, "gang_allocate_chunked"),   # forced, too large
+], ids=["auto-fits", "auto-too-large", "forced-too-large"])
+def test_pallas_tier_chosen_by_smem_budget(monkeypatch, conf, n_tasks,
+                                           kernel):
+    """On a TPU the Pallas tier is chosen up front only where its
+    scalar-prefetch operands fit SMEM (tests/test_tpu_compile.py brackets
+    the bound against the chip's compiler); larger batches go to the XLA
+    kernels instead of crashing into the breaker."""
+    from types import SimpleNamespace
+
+    from volcano_tpu.framework import solver as solver_mod
+    h = _populate(Harness(conf))
+    ssn = h.open_session()
+    monkeypatch.setattr(solver_mod.jax, "default_backend", lambda: "tpu")
+    j = n_tasks // 8
+    batch = SimpleNamespace(t_pad=n_tasks, j_pad=j, g_pad=j,
+                            pool_queue=np.zeros(8, np.int32))
+    fn, kwargs = ssn.solver._select_kernel(batch)
+    assert fn.__name__ == kernel
+    assert "interpret" not in kwargs or kwargs["interpret"] is False
+    h.close_session()
+
+
+def test_pallas_refuses_a_backend_it_cannot_run_on(monkeypatch):
+    """Interpret mode is for the CPU the tests run on; anywhere else a
+    forced Pallas kernel fails loudly instead of emulating."""
+    from volcano_tpu.framework import solver as solver_mod
+    h = _populate(Harness(CONF_PALLAS))
+    ssn = h.open_session()
+    monkeypatch.setattr(solver_mod.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ssn.solver._select_kernel()
     h.close_session()
 
 

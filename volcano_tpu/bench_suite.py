@@ -128,6 +128,58 @@ def _populate(store, n_nodes, n_jobs, gang, queues=None, cpu="2",
 
 
 
+# preempt over a vectorizable plugin chain: every enabled preemptable
+# plugin has a compiled form, so the victim kernel (ops/victims.py) serves
+# it unless `victims.kernel: "off"` forces the Python walk
+CONF_VICTIMS = """
+actions: "preempt"
+tiers:
+- plugins:
+  - name: priority
+  - name: conformance
+  - name: gang
+- plugins:
+  - name: predicates
+  - name: nodeorder
+"""
+
+
+def victim_env(conf_text, vn_nodes=2000, n_low=250, n_high=125):
+    """Preemption under pressure: ``n_low`` low-priority gangs of 8
+    (minAvailable 4) fill every node, and ``n_high`` high-priority gangs
+    of 8 wait for room. Returns _cycle_env's (store, cache, binder,
+    conf)."""
+    from volcano_tpu.models.objects import ObjectMeta, PriorityClass
+    from volcano_tpu.utils.test_utils import (build_node, build_pod,
+                                              build_pod_group, build_queue)
+    store, cache, binder, conf = _cycle_env(conf_text)
+    store.create("queues", build_queue("default", weight=1))
+    store.create("priorityclasses", PriorityClass(
+        metadata=ObjectMeta(name="high"), value=100))
+    store.create("priorityclasses", PriorityClass(
+        metadata=ObjectMeta(name="low"), value=1))
+    for i in range(vn_nodes):
+        store.create("nodes", build_node(
+            f"node-{i}", {"cpu": "16", "memory": "32Gi"}))
+    for j in range(n_low):
+        store.create("podgroups", build_pod_group(
+            f"lo-{j}", "ns1", "default", 4, phase="Running",
+            priority_class="low"))
+        for t in range(8):
+            store.create("pods", build_pod(
+                "ns1", f"lo-{j}-{t}", f"node-{(j * 8 + t) % vn_nodes}",
+                "Running", {"cpu": "14", "memory": "28Gi"}, f"lo-{j}"))
+    for j in range(n_high):
+        store.create("podgroups", build_pod_group(
+            f"hi-{j}", "ns1", "default", 8, phase="Inqueue",
+            priority_class="high"))
+        for t in range(8):
+            store.create("pods", build_pod(
+                "ns1", f"hi-{j}-{t}", "", "Pending",
+                {"cpu": "14", "memory": "28Gi"}, f"hi-{j}"))
+    return store, cache, binder, conf
+
+
 def _warm_cycle(conf_text: str, runs: int = 3, flush_timeout: float = 120.0,
                 **populate_kwargs):
     """Cold cycle (compile) on one env, then measured warm cycles on fresh

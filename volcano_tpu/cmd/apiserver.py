@@ -25,8 +25,6 @@ from ..models.objects import (Node, NodeStatus, ObjectMeta, Queue, QueueSpec)
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import apply_env_platform
-    apply_env_platform()
     parser = argparse.ArgumentParser(prog="vc-apiserver")
     parser.add_argument("--host", default="127.0.0.1")
     parser.add_argument("--port", type=int, default=8181)

@@ -45,8 +45,6 @@ def run_controllers(store: ObjectStore, args) -> ControllerManager:
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import apply_env_platform
-    apply_env_platform()
     parser = argparse.ArgumentParser(prog="vc-controller-manager")
     add_flags(parser)
     args = parser.parse_args(argv)

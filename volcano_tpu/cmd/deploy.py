@@ -46,8 +46,6 @@ def log(msg: str) -> None:
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import apply_env_platform
-    apply_env_platform()
     parser = argparse.ArgumentParser(prog="vc-deploy")
     add_flags(parser)
     args = parser.parse_args(argv)

@@ -73,14 +73,14 @@ def run_scheduler(store: ObjectStore, args) -> Scheduler:
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import apply_env_platform
-    apply_env_platform()
     parser = argparse.ArgumentParser(prog="vc-scheduler")
     add_flags(parser)
     args = parser.parse_args(argv)
     if args.version:
         from ..version import print_version_and_exit
         print_version_and_exit()
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..trace import tracer
     if args.enable_tracing:
         # an explicit --trace-cycles wins; else VOLCANO_TRACE_CAPACITY;

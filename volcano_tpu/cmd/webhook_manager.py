@@ -35,8 +35,6 @@ def add_flags(parser: argparse.ArgumentParser) -> None:
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import apply_env_platform
-    apply_env_platform()
     parser = argparse.ArgumentParser(prog="vc-webhook-manager")
     add_flags(parser)
     args = parser.parse_args(argv)

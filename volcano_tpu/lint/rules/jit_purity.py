@@ -33,7 +33,7 @@ def _is_jit_expr(node: ast.AST) -> bool:
     `functools.partial(jit, ...)` decorator/callee expressions."""
     dn = dotted_name(node)
     if dn in ("jit", "jax.jit", "shard_map",
-              "jax.experimental.shard_map.shard_map"):
+              "jax.shard_map"):
         return True
     if isinstance(node, ast.Call):
         fdn = dotted_name(node.func)

@@ -91,6 +91,10 @@ SOLVER_BREAKER_OPEN = f"{NS}_solver_breaker_open"
 # native / chunked / scan) — the auto-selection proof for the mesh
 # default (docs/design/sharded_kernel.md)
 SOLVER_KERNEL_RUNS = f"{NS}_solver_kernel_runs_total"
+# context builds that ran the per-node Python sweep for plugins with only
+# host predicate fns (solver._host_predicate_mask) — the slow path the
+# chip smoke asserts never engaged on the default conf
+SOLVER_HOST_PREDICATE = f"{NS}_solver_host_predicate_fallback_total"
 # control-plane failover (docs/design/failover.md): writes rejected for a
 # superseded fencing token, cache-vs-store anti-entropy divergences by
 # kind, remote-store transient write retries, and watch-stream restarts

@@ -31,10 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .allocate import make_pool_select
@@ -499,12 +496,8 @@ def make_sharded_gang_allocate(mesh: Mesh, axis: str = "nodes",
                                      **kw)
         body = partial(base, allow_pipeline=allow_pipeline,
                        ns_live=ns_live, axis=axis)
-    try:
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except TypeError:  # pre-0.9 jax
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    sm = shard_map(body, mesh=mesh, in_specs=in_specs,
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(sm)
 
 
@@ -636,27 +629,21 @@ def build_shard_plan(n_rows: int, n_devices: int, pressure=None,
     return plan
 
 
-def shard_synth(mesh: Mesh, sa, axis: str = "nodes"):
-    """Device-put a SynthArrays set with node-axis sharding over ``mesh``.
-    Returns the argument list for make_sharded_gang_allocate's fn, minus
-    weights."""
+def synth_shardings(mesh: Mesh, axis: str = "nodes") -> list:
+    """The NamedSharding of each SynthArrays.args entry on ``mesh``: node
+    axes split over the mesh, everything else replicated."""
     n = NamedSharding(mesh, P(axis))
     nr = NamedSharding(mesh, P(axis, None))
     gn = NamedSharding(mesh, P(None, axis))
     rep = NamedSharding(mesh, P())
-    put = jax.device_put
-    return [
-        put(sa.task_group, rep), put(sa.task_job, rep),
-        put(sa.task_valid, rep), put(sa.group_req, rep),
-        put(sa.group_mask, gn), put(sa.group_static_score, gn),
-        put(sa.task_bucket, rep), put(sa.group_pack_bonus, rep),
-        put(sa.job_min_available, rep), put(sa.job_ready_base, rep),
-        put(sa.job_task_start, rep), put(sa.job_n_tasks, rep),
-        put(sa.job_queue, rep), put(sa.pool_queue, rep),
-        put(sa.pool_ns, rep), put(sa.pool_job_start, rep),
-        put(sa.pool_njobs, rep), put(sa.ns_weight, rep),
-        put(sa.ns_alloc0, rep), put(sa.ns_total, rep),
-        put(sa.queue_deserved, rep), put(sa.queue_alloc0, rep),
-        put(sa.node_idle, nr), put(sa.node_future, nr),
-        put(sa.node_alloc, nr), put(sa.node_ntasks, n),
-        put(sa.node_max_tasks, n), put(sa.eps, rep)]
+    return [rep, rep, rep, rep, gn, gn, rep, rep, rep, rep, rep, rep, rep,
+            rep, rep, rep, rep, rep, rep, rep, rep, rep, nr, nr, nr, n, n,
+            rep]
+
+
+def shard_synth(mesh: Mesh, sa, axis: str = "nodes"):
+    """Device-put a SynthArrays set with node-axis sharding over ``mesh``.
+    Returns the argument list for make_sharded_gang_allocate's fn, minus
+    weights."""
+    return [jax.device_put(a, s)
+            for a, s in zip(sa.args, synth_shardings(mesh, axis))]

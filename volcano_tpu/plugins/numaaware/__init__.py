@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Set
 
+import numpy as np
+
 from ...framework.plugin import Plugin
 from ...framework.registry import register_plugin_builder
 from ...models.resource import CPU, milli_value
@@ -67,6 +69,20 @@ def generate_node_res_numa_sets(nodes) -> Dict[str, Dict[str, Set[int]]]:
         out[name] = {res: set(ri.allocatable)
                      for res, ri in node.numa_scheduler_info.numa_res_map.items()}
     return out
+
+
+def _policy_without_numa_mask(batch, narr, feats):
+    """Groups whose task is Guaranteed with a topology policy fit no node
+    of a cluster without NUMA topology; None when no group is one."""
+    blocked = [g for g, members in enumerate(batch.group_members)
+               if batch.tasks[members[0]].topology_policy
+               not in ("", POLICY_NONE)
+               and is_guaranteed(batch.tasks[members[0]].pod)]
+    if not blocked:
+        return None
+    mask = np.ones((batch.g_pad, narr.n_pad), bool)
+    mask[blocked] = False
+    return mask
 
 
 class NumaAwarePlugin(Plugin):
@@ -142,6 +158,13 @@ class NumaAwarePlugin(Plugin):
                 self.assign_res.setdefault(task.uid, {})[node.name] = sets
 
         ssn.add_predicate_fn(NAME, predicate_fn)
+        if ssn.solver is not None and not numa_nodes:
+            # no node carries NUMA topology: the predicate then records
+            # nothing and vetoes only a Guaranteed task with a topology
+            # policy, on every node — a [G, N] mask states that exactly,
+            # and the solver skips its per-node Python sweep
+            ssn.solver.mark_vectorized(NAME)
+            ssn.solver.add_mask_fn(_policy_without_numa_mask)
 
         def batch_node_order_fn(task, node_infos) -> Dict[str, float]:
             """numaaware.go:160-183 — fewer NUMA nodes spanned is better."""
