@@ -191,6 +191,31 @@ def tight_cluster(h):
     return h
 
 
+def skewed_cluster(h):
+    """Three nodes at distinct fill levels -> distinct binpack scores ->
+    nonzero shifted score mass beyond the top-1, and one pending job."""
+    h.add("queues", build_queue("default", weight=1))
+    for i, used in enumerate(("2", "6", "10")):
+        h.add("nodes", build_node(f"node-{i}",
+                                  {"cpu": "16", "memory": "32Gi"}))
+        h.add("podgroups", build_pod_group(
+            f"fill-{i}", "ns1", "default", 1, phase="Running"))
+        h.add("pods", build_pod(
+            "ns1", f"fill-{i}", f"node-{i}", "Running",
+            {"cpu": used, "memory": "1Gi"}, f"fill-{i}"))
+    h.add("podgroups", build_pod_group("pg-0", "ns1", "default", 1,
+                                       phase="Inqueue"))
+    h.add("pods", build_pod("ns1", "p0", "", "Pending",
+                            {"cpu": "2", "memory": "2Gi"}, "pg-0"))
+    return h
+
+
+# a k=1 shortlist against a 0.99 coverage floor: the pre-kernel guard
+LOW_COVERAGE_CONF = conf_with_solver(
+    **{"prune.enable": "true", "prune.k": 1, "prune.coverage_floor": 0.99,
+       "prune.demand_aware": "off"})
+
+
 class TestLossGuard:
     def test_exhausted_shortlist_red_without_guard(self):
         """Proves the guard is load-bearing: with `prune.guard: off`
@@ -225,35 +250,41 @@ class TestLossGuard:
         """A k=1 shortlist over distinct static scores covers less of
         the feasible score mass than the floor: the pre-kernel guard
         must fall back (and the binds must equal the dense run's)."""
-
-        def skewed(h):
-            h.add("queues", build_queue("default", weight=1))
-            # three nodes at distinct fill levels -> distinct binpack
-            # scores -> nonzero shifted score mass beyond the top-1
-            for i, used in enumerate(("2", "6", "10")):
-                h.add("nodes", build_node(f"node-{i}",
-                                          {"cpu": "16", "memory": "32Gi"}))
-                h.add("podgroups", build_pod_group(
-                    f"fill-{i}", "ns1", "default", 1, phase="Running"))
-                h.add("pods", build_pod(
-                    "ns1", f"fill-{i}", f"node-{i}", "Running",
-                    {"cpu": used, "memory": "1Gi"}, f"fill-{i}"))
-            h.add("podgroups", build_pod_group("pg-0", "ns1", "default", 1,
-                                               phase="Inqueue"))
-            h.add("pods", build_pod("ns1", "p0", "", "Pending",
-                                    {"cpu": "2", "memory": "2Gi"}, "pg-0"))
-            return h
-
         f0 = fallback_totals()
-        pruned = run_cluster(skewed, conf_with_solver(
-            **{"prune.enable": "true", "prune.k": 1,
-               "prune.coverage_floor": 0.99,
-               "prune.demand_aware": "off"}))
-        dense = run_cluster(skewed, conf_with_solver(
+        pruned = run_cluster(skewed_cluster, LOW_COVERAGE_CONF)
+        dense = run_cluster(skewed_cluster, conf_with_solver(
             **{"prune.enable": "off"}))
         assert pruned.binds == dense.binds
         f1 = fallback_totals()
         assert f1["low_coverage"] > f0["low_coverage"]
+
+    def test_pre_guard_fallback_tags_the_place_span(self):
+        """A traced cycle says which guard sent the call to full width:
+        the open ``solver.place`` span carries the reason and the pairs
+        behind it."""
+        from volcano_tpu.trace import tracer
+        h = skewed_cluster(Harness(LOW_COVERAGE_CONF))
+        h.open_session()
+        tracer.enable()
+        try:
+            with tracer.cycle():
+                h.run_actions("enqueue", "allocate")
+            root = tracer.last_record().root
+        finally:
+            tracer.disable()
+            tracer.reset()
+        h.close_session()
+
+        def walk(s):
+            yield s
+            for c in s.children or ():
+                yield from walk(c)
+
+        places = [s for s in walk(root) if s.name == "solver.place"]
+        assert places
+        assert places[0].tags["prune_fallback"] == "low_coverage"
+        assert places[0].tags["fallback_pairs"] >= 1
+        assert len(h.binds) == 1
 
     def test_demand_aware_widening_avoids_exhaustion(self):
         """A batch whose capacity demand exceeds k nodes would exhaust

@@ -18,6 +18,7 @@ per-preemptor full-cluster sweeps.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict, List
 
 from ..framework.plugin import Action
@@ -40,7 +41,8 @@ class PreemptAction(Action):
         # flush once (gauge keeps last-set semantics, counter the total).
         # Local state, not attributes: the registered action instance is a
         # process-global singleton.
-        stats = {"attempts": 0, "last_victims": -1}
+        stats = {"attempts": 0, "last_victims": -1, "phase_attempts": 0,
+                 "select_s": 0.0}
         try:
             self._execute(ssn, stats)
         finally:
@@ -55,23 +57,26 @@ class PreemptAction(Action):
         under_request: List[JobInfo] = []
         queues = {}
 
-        for job in ssn.jobs.values():
-            if job.pod_group.status.phase == PodGroupPhase.PENDING:
-                continue
-            vr = ssn.job_valid(job)
-            if vr is not None and not vr.passed:
-                continue
-            queue = ssn.queues.get(job.queue)
-            if queue is None:
-                continue
-            queues[queue.uid] = queue
-            if ssn.job_starving(job):
-                preemptors_map.setdefault(job.queue, []).append(job)
-                under_request.append(job)
-                preemptor_tasks[job.uid] = self._pending_tasks(ssn, job)
+        with trace.span("preempt.scan"):
+            for job in ssn.jobs.values():
+                if job.pod_group.status.phase == PodGroupPhase.PENDING:
+                    continue
+                vr = ssn.job_valid(job)
+                if vr is not None and not vr.passed:
+                    continue
+                queue = ssn.queues.get(job.queue)
+                if queue is None:
+                    continue
+                queues[queue.uid] = queue
+                if ssn.job_starving(job):
+                    preemptors_map.setdefault(job.queue, []).append(job)
+                    under_request.append(job)
+                    preemptor_tasks[job.uid] = self._pending_tasks(ssn, job)
+            trace.add_tags(starving=len(under_request))
 
         if not under_request:
-            self._victim_tasks(ssn)
+            with trace.span("preempt.victim_tasks"):
+                self._victim_tasks(ssn)
             return
 
         # one batched encode for ALL preemptor tasks of the action
@@ -87,52 +92,70 @@ class PreemptAction(Action):
         # priority-queue pop/re-push like the reference's preemptorsQueue
         # (rebuilding the order per pop is O(n^2 log n) at 5k starving jobs)
         import heapq
-        for queue in queues.values():
-            jobs_list = preemptors_map.get(queue.name)
-            if not jobs_list:
-                continue
-            heap = [job_key(j) for j in jobs_list]
-            heapq.heapify(heap)
-            while heap:
-                preemptor_job = heapq.heappop(heap).obj
-
-                stmt = Statement(ssn)
-                ctx.checkpoint()
-                assigned = False
-                while ssn.job_starving(preemptor_job):
-                    tasks = preemptor_tasks.get(preemptor_job.uid)
-                    if not tasks:
-                        break
-                    preemptor = tasks.pop(0)
-                    if self._preempt(ssn, ctx, stmt, preemptor, INTER_JOB, stats):
-                        assigned = True
-
-                if ssn.job_pipelined(preemptor_job):
-                    stmt.commit()
-                    ctx.commit()
-                else:
-                    stmt.discard()
-                    ctx.rollback()
+        with trace.span("preempt.inter_job"):
+            for queue in queues.values():
+                jobs_list = preemptors_map.get(queue.name)
+                if not jobs_list:
                     continue
-                if assigned:
-                    heapq.heappush(heap, job_key(preemptor_job))
+                heap = [job_key(j) for j in jobs_list]
+                heapq.heapify(heap)
+                while heap:
+                    preemptor_job = heapq.heappop(heap).obj
+
+                    stmt = Statement(ssn)
+                    ctx.checkpoint()
+                    assigned = False
+                    while ssn.job_starving(preemptor_job):
+                        tasks = preemptor_tasks.get(preemptor_job.uid)
+                        if not tasks:
+                            break
+                        preemptor = tasks.pop(0)
+                        if self._preempt(ssn, ctx, stmt, preemptor,
+                                         INTER_JOB, stats):
+                            assigned = True
+
+                    if ssn.job_pipelined(preemptor_job):
+                        stmt.commit()
+                        ctx.commit()
+                    else:
+                        stmt.discard()
+                        ctx.rollback()
+                        continue
+                    if assigned:
+                        heapq.heappush(heap, job_key(preemptor_job))
+            self._tag_phase(stats)
 
         # preemption between tasks within a job (preempt.go:146-183)
-        for job in under_request:
-            tasks = self._pending_tasks(ssn, job)
-            while tasks:
-                preemptor = tasks.pop(0)
-                stmt = Statement(ssn)
-                ctx.checkpoint()
-                assigned = self._preempt(ssn, ctx, stmt, preemptor, INTRA_JOB, stats)
-                stmt.commit()
-                ctx.commit()
-                if not assigned:
-                    break
+        with trace.span("preempt.intra_job"):
+            for job in under_request:
+                tasks = self._pending_tasks(ssn, job)
+                while tasks:
+                    preemptor = tasks.pop(0)
+                    stmt = Statement(ssn)
+                    ctx.checkpoint()
+                    assigned = self._preempt(ssn, ctx, stmt, preemptor,
+                                             INTRA_JOB, stats)
+                    stmt.commit()
+                    ctx.commit()
+                    if not assigned:
+                        break
+            self._tag_phase(stats)
 
-        self._victim_tasks(ssn)
+        with trace.span("preempt.victim_tasks"):
+            self._victim_tasks(ssn)
         trace.add_tags(attempts=stats["attempts"],
                        victims=max(0, stats["last_victims"]))
+
+    @staticmethod
+    def _tag_phase(stats) -> None:
+        """Tag the open phase span with its preemptor attempts and the
+        time spent choosing victims (``PreemptContext.place``, interleaved
+        per preemptor with the statement's evictions, so a tag and not a
+        span), then restart both tallies for the next phase."""
+        trace.add_tags(attempts=stats["attempts"] - stats["phase_attempts"],
+                       select_ms=round(stats["select_s"] * 1000.0, 3))
+        stats["phase_attempts"] = stats["attempts"]
+        stats["select_s"] = 0.0
 
     # ------------------------------------------------------------------
 
@@ -155,7 +178,9 @@ class PreemptAction(Action):
         def note(victims):
             stats["last_victims"] = len(victims)
 
+        t0 = time.perf_counter()
         res = ctx.place(preemptor, mode, victim_cb=note)
+        stats["select_s"] += time.perf_counter() - t0
         stats["attempts"] += 1
         if res is None:
             return False

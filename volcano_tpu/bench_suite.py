@@ -574,31 +574,6 @@ def config_reclaim(n_nodes=10_000, n_running=1_250, n_pending=625) -> Dict:
             "platform": _platform()}
 
 
-def capture_traces() -> None:
-    """jax.profiler trace artifacts (SURVEY §5.1), captured AFTER the
-    measurements — host-side tracing inflates full-cycle latency up to
-    5x, so the recorded numbers must never run under the profiler. One
-    reduced-shape pass per config class: a full cycle (host+device
-    overlap) and the placement kernel. Paths print to stderr; opt out
-    with VOLCANO_BENCH_TRACE=0; failures never break the bench."""
-    import os
-
-    import jax
-    if os.environ.get("VOLCANO_BENCH_TRACE", "1") == "0":
-        return
-    base = os.path.join(os.getcwd(), "traces")
-    for name, fn in (("full_cycle", config_2),
-                     ("kernel", lambda: config_5(5_000, 1_000))):
-        path = os.path.join(base, name)
-        try:
-            os.makedirs(path, exist_ok=True)
-            with jax.profiler.trace(path):
-                fn()
-            log(f"trace for {name}: {path}")
-        except Exception as e:   # tracing must never fail the bench
-            log(f"trace capture for {name} failed ({e})")
-
-
 def machine_calibration() -> Dict:
     """Co-tenant load fingerprint: wall time of a fixed single-core numpy
     workload, recorded alongside the suite so readers can compare two
@@ -660,5 +635,4 @@ def run_all(full_scale: bool = True) -> List[Dict]:
             n_nodes=1000, resident_jobs=625, arrival_jobs=25, cycles=10))
     results.append(machine_calibration())   # load may drift over the run
     log(f"calibration (end): {results[-1]}")
-    capture_traces()
     return results

@@ -1158,7 +1158,6 @@ class BatchSolver:
 
         Returns (assign [T] np, pipelined, ready, kept, served_tier)."""
         from ..metrics import metrics as m
-        from ..ops import kernel_span
         from ..ops.allocate import gang_allocate_chunked
 
         reduced_host = None
@@ -1221,10 +1220,10 @@ class BatchSolver:
         for i, (tier, kfn, kkwargs) in enumerate(eligible):
             span_name = "sharded" if tier == "sharded" else kfn.__name__
             try:
-                with kernel_span(span_name, g_pad=int(batch.g_pad),
-                                 n_pad=n_axis,
-                                 t_pad=int(batch.task_group.shape[0]),
-                                 pruned=reduced is not None):
+                with trace.span("kernel", kernel=span_name,
+                                g_pad=int(batch.g_pad), n_pad=n_axis,
+                                t_pad=int(batch.task_group.shape[0]),
+                                pruned=reduced is not None):
                     if tier == "sharded":
                         assign, pipelined, ready, kept = self._run_sharded(
                             batch, narr, gmask, static_score, task_bucket,
@@ -1237,9 +1236,7 @@ class BatchSolver:
                             account_transfer = True
                             # per-tier sub-phase attribution: the input
                             # tensor assembly and the host->device node
-                            # staging get their own spans (compile vs
-                            # execute is the kernel span's `compiled`
-                            # tag, ops/kernel_span)
+                            # staging get their own spans
                             with trace.span("tensor_build"):
                                 with trace.span("transfer"):
                                     if reduced_host is not None:
@@ -1300,15 +1297,21 @@ class BatchSolver:
                                         for a in slot_kwargs.values())
                             m.inc(m.DEVICE_TRANSFER_BYTES, float(xfer))
                             trace.add_tags(transfer_bytes=xfer)
+                        # dispatch: the kernel call, with its wrapper's
+                        # host work and any compile it triggers;
+                        # readback: the wait for the device and the copy
                         with trace.span("execute"):
-                            assign, pipelined, ready, kept, _ = kfn(
-                                *kernel_inputs,
-                                allow_pipeline=allow_pipeline,
-                                ns_live=ns_live, **slot_kwargs, **kkwargs)
+                            with trace.span("dispatch"):
+                                assign, pipelined, ready, kept, _ = kfn(
+                                    *kernel_inputs,
+                                    allow_pipeline=allow_pipeline,
+                                    ns_live=ns_live, **slot_kwargs,
+                                    **kkwargs)
                             # blocks until the device finishes (a
                             # deferred kernel crash surfaces here,
                             # inside the tier's try)
-                            assign = np.asarray(assign)
+                            with trace.span("readback"):
+                                assign = np.asarray(assign)
             except Exception:
                 if i + 1 >= len(eligible):
                     raise   # last resort crashed too: fail the cycle
@@ -1376,10 +1379,17 @@ class BatchSolver:
         feasible valid task went unplaced while any pair's shortlist
         was truncated) — every fallback counted once on
         volcano_prune_fallback_total{reason}, so pruning can never lose
-        a placement the dense kernel would have made."""
+        a placement the dense kernel would have made. A fallback also
+        tags the open ``solver.place`` span with ``prune_fallback`` (the
+        reason) and ``fallback_pairs``."""
         from ..metrics import metrics as m
         from ..ops import prune as _prune
         from ..trace import explain as _explain
+
+        def fall_back(reason, pairs=0):
+            m.inc(m.PRUNE_FALLBACK, reason=reason)
+            trace.add_tags(prune_fallback=reason, fallback_pairs=int(pairs))
+
         plan = None
         if self.mesh is not None:
             # the ShardPlan's contiguous ranges are the two-level
@@ -1398,7 +1408,7 @@ class BatchSolver:
         except Exception:
             _logger.exception("shortlist distillation crashed; running "
                               "the full-width kernel for this cycle")
-            m.inc(m.PRUNE_FALLBACK, reason="crash")
+            fall_back("crash")
             return None
         guard = ctx.pre_guard()
         if guard is not None:
@@ -1408,7 +1418,7 @@ class BatchSolver:
             reason, count = guard
             ctx.fallback = reason
             ctx.fallback_pairs = int(count)
-            m.inc(m.PRUNE_FALLBACK, reason=reason)
+            fall_back(reason, count)
             _explain.note_prune(ctx.summary())
             return None
         try:
@@ -1423,14 +1433,14 @@ class BatchSolver:
             _logger.exception("pruned kernel ladder crashed at every "
                               "tier; running the full-width kernel")
             ctx.fallback = "crash"
-            m.inc(m.PRUNE_FALLBACK, reason="crash")
+            fall_back("crash")
             _explain.note_prune(ctx.summary())
             return None
         assign_r, pipelined, ready, kept, tier = out
         assign = ctx.map_assign(assign_r)
         if ctx.post_guard(assign, batch):
             ctx.fallback = "shortlist_exhausted"
-            m.inc(m.PRUNE_FALLBACK, reason="shortlist_exhausted")
+            fall_back("shortlist_exhausted", ctx.truncated.sum())
             _explain.note_prune(ctx.summary())
             return None
         m.inc(m.PRUNE_RUNS, level=ctx.level)
@@ -1579,7 +1589,7 @@ class BatchSolver:
         from ..metrics import metrics as m
         # sub-phase attribution: the node-tensor staging + layout
         # gathers are the sharded tier's "tensor build" (the small
-        # replicated put()s ride the execute span with the dispatch).
+        # replicated put()s ride the execute/dispatch span).
         # try/finally: a crashing build must pop its span — the tier
         # ladder catches the crash and the fallback tier's spans would
         # otherwise nest under a dead parent
@@ -1628,34 +1638,34 @@ class BatchSolver:
         finally:
             tb.__exit__()
 
-        ex = trace.span("execute")
-        ex.__enter__()
-        try:
-            assign, pipelined, ready, kept, _idle = fn(
-                put(batch.task_group, rep), put(batch.task_job, rep),
-                put(batch.task_valid, rep), put(batch.group_req, rep),
-                put(gmask_l, gn), put(score_l, gn),
-                put(task_bucket, rep), put(pack_bonus, rep),
-                put(batch.job_min_available, rep),
-                put(batch.job_ready_base, rep),
-                put(batch.job_task_start, rep), put(batch.job_n_tasks, rep),
-                put(batch.job_queue, rep), put(batch.pool_queue, rep),
-                put(batch.pool_ns, rep), put(batch.pool_job_start, rep),
-                put(batch.pool_njobs, rep), put(ns_weight, rep),
-                put(ns_alloc0, rep), put(ns_total, rep),
-                put(q_deserved, rep), put(q_alloc0, rep),
-                dev_nodes["idle"], dev_nodes["future_idle"],
-                dev_nodes["allocatable"], dev_nodes["n_tasks"],
-                dev_nodes["max_tasks"],
-                put(np.asarray(eps), rep), self.score_weights(), *slot_args)
-            # layout index -> node index (the gather is strictly increasing
-            # over real rows, so tie-breaks already matched node order)
-            a = np.asarray(assign)
-        finally:
-            ex.__exit__()
+        with trace.span("execute"):
+            with trace.span("dispatch"):
+                assign, pipelined, ready, kept, _idle = fn(
+                    put(batch.task_group, rep), put(batch.task_job, rep),
+                    put(batch.task_valid, rep), put(batch.group_req, rep),
+                    put(gmask_l, gn), put(score_l, gn),
+                    put(task_bucket, rep), put(pack_bonus, rep),
+                    put(batch.job_min_available, rep),
+                    put(batch.job_ready_base, rep),
+                    put(batch.job_task_start, rep),
+                    put(batch.job_n_tasks, rep),
+                    put(batch.job_queue, rep), put(batch.pool_queue, rep),
+                    put(batch.pool_ns, rep), put(batch.pool_job_start, rep),
+                    put(batch.pool_njobs, rep), put(ns_weight, rep),
+                    put(ns_alloc0, rep), put(ns_total, rep),
+                    put(q_deserved, rep), put(q_alloc0, rep),
+                    dev_nodes["idle"], dev_nodes["future_idle"],
+                    dev_nodes["allocatable"], dev_nodes["n_tasks"],
+                    dev_nodes["max_tasks"],
+                    put(np.asarray(eps), rep), self.score_weights(),
+                    *slot_args)
+            with trace.span("readback"):
+                a = np.asarray(assign)
         if xfer[0]:
             m.inc(m.DEVICE_TRANSFER_BYTES, float(xfer[0]))
             trace.add_tags(transfer_bytes=xfer[0])
+        # layout index -> node index (the gather is strictly increasing
+        # over real rows, so tie-breaks already matched node order)
         assign = np.where(a >= 0,
                           plan.gather[np.clip(a, 0, plan.n_layout - 1)],
                           -1).astype(np.int32)

@@ -6,7 +6,7 @@ read executes at TRACE time — then never again, silently) and forever
 after as compiled XLA (where it doesn't exist at all).  Worse, a value-
 dependent host call forces a retrace per shape.  The contract for
 ``ops/``: kernel bodies are pure array programs; telemetry lives in the
-host-side wrappers (``kernel_span`` et al.).
+host-side wrappers (the solver's ``kernel`` span et al.).
 
 Detection is lexical: functions decorated with ``jit``/``jax.jit``
 (including ``partial(jax.jit, ...)``) or passed by name to

@@ -107,13 +107,14 @@ WATCH_RESTARTS = f"{NS}_watch_restarts_total"
 # ledger's transition chain (trace/ledger.py), observed at completion
 POD_E2E_LATENCY = f"{NS}_pod_e2e_latency_milliseconds"
 POD_HOP_LATENCY = f"{NS}_pod_hop_latency_milliseconds"
-# solver & backend profiling hooks: placement-kernel dispatches by
-# compile-cache outcome (result="hit"|"miss"), recompiles forced by a NEW
-# padded-shape bucket of an already-seen kernel (the shape-churn signal),
-# host->device bytes staged as kernel inputs, and backend-init probe
-# verdicts (outcome="alive"|"dead"|"hang")
-SOLVER_COMPILE_CACHE = f"{NS}_solver_compile_cache_total"
-SOLVER_SHAPE_RECOMPILES = f"{NS}_solver_padded_shape_recompile_total"
+# solver & backend profiling hooks: JAX backend compiles by jitted
+# function (a load from the persistent cache counts too: JAX times both
+# as a backend compile) and compile seconds by stage
+# (stage="trace"|"lower"|"backend"), both from JAX's own monitoring
+# events (trace/tracer.py); host->device bytes staged as kernel inputs,
+# and backend-init probe verdicts (outcome="alive"|"dead"|"hang")
+JIT_COMPILES = f"{NS}_jit_compiles_total"
+JIT_COMPILE_SECONDS = f"{NS}_jit_compile_seconds_total"
 DEVICE_TRANSFER_BYTES = f"{NS}_solver_device_transfer_bytes_total"
 BACKEND_PROBE = f"{NS}_backend_probe_total"
 # incremental steady-state cycle (docs/design/incremental_cycle.md):

@@ -43,7 +43,7 @@ COUNTER_KEYS = (
     m.WATCH_RESTARTS,
     m.UNSCHEDULABLE_REASON,
     m.SOLVER_FALLBACK,
-    m.SOLVER_SHAPE_RECOMPILES,
+    m.JIT_COMPILES,
     m.DEVICE_TRANSFER_BYTES,
 )
 GAUGE_KEYS = (m.QUARANTINED_TASKS,)

@@ -7,36 +7,3 @@ from .score import (ScoreWeights, balanced_allocation_score,  # noqa: F401
                     most_requested_score, node_score)
 from .allocate import gang_allocate  # noqa: F401
 
-# padded-shape buckets already served per kernel: the first invocation at
-# a bucket is the one that pays the jit compile (or, for the native
-# solver, its candidate-table build), so its kernel span is tagged
-# compiled=True — the compile-vs-execute attribution for /debug/trace
-_seen_shape_buckets: set = set()
-# kernels that have compiled at least one bucket: a NEW bucket for an
-# already-seen kernel is a padded-shape RECOMPILE (shape churn defeating
-# the bucketing — the signal volcano_solver_padded_shape_recompile_total
-# exists to catch; a kernel's very first bucket is just its cold compile)
-_seen_kernels: set = set()
-
-
-def kernel_span(kernel: str, **shape_tags):
-    """Flight-recorder span for one placement-kernel invocation, tagging
-    the kernel name, the padded-shape bucket and whether this call is the
-    bucket's first (compile) run. Every call also counts into the
-    compile-cache metrics: ``volcano_solver_compile_cache_total{result}``
-    (hit/miss) and, for a miss on an already-warm kernel,
-    ``volcano_solver_padded_shape_recompile_total{kernel}``."""
-    from ..metrics import metrics as m
-    from ..trace import tracer
-    key = (kernel, tuple(sorted(shape_tags.items())))
-    compiled = key not in _seen_shape_buckets
-    if compiled:
-        _seen_shape_buckets.add(key)
-        m.inc(m.SOLVER_COMPILE_CACHE, result="miss")
-        if kernel in _seen_kernels:
-            m.inc(m.SOLVER_SHAPE_RECOMPILES, kernel=kernel)
-        _seen_kernels.add(kernel)
-    else:
-        m.inc(m.SOLVER_COMPILE_CACHE, result="hit")
-    return tracer.span("kernel", kernel=kernel, compiled=compiled,
-                       **shape_tags)

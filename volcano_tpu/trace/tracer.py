@@ -18,6 +18,21 @@ Thread model: spans nest per-thread (the cycle runs on one thread); a
 ``span()`` on a thread with no open cycle is a no-op. Executor threads
 record into the flight recorder through ``async_span`` (the bind flush),
 which tags its spans with the cycle sequence they follow.
+
+While the recorder is on, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a profile taken meanwhile
+shows the span tree in its host plane, on the device trace's clock and on
+the thread that ran it.
+
+Compiles: one ``jax.monitoring`` listener per process (registered at
+import) counts every backend compile into ``volcano_jit_compiles_total``
+and the seconds of each stage into ``volcano_jit_compile_seconds_total``,
+tracing on or off. While the recorder is on, each JAX compile event also
+becomes a ``compile`` span (tags ``fun``, ``stage``) under the span open
+on the compiling thread. The events arrive when a stage ends, so compile
+spans are placed after the fact and are not mirrored; a stage's own
+nested compiles (the traces of the jitted functions it calls) become its
+children.
 """
 
 from __future__ import annotations
@@ -27,6 +42,9 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 _perf = time.perf_counter
 
@@ -93,12 +111,21 @@ class _NullCtx:
 _NULL = _NullCtx()
 
 
+def _annotate(name: str) -> TraceAnnotation:
+    """The span's twin in the profiler's host plane (a no-op in C++ when
+    no profile is being taken)."""
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _SpanCtx:
-    __slots__ = ("_span", "_stack")
+    __slots__ = ("_span", "_stack", "_ann")
 
     def __init__(self, span: Span, stack: list):
         self._span = span
         self._stack = stack
+        self._ann = _annotate(span.name)
 
     def __enter__(self):
         return self._span
@@ -106,6 +133,7 @@ class _SpanCtx:
     def __exit__(self, *exc):
         s = self._span
         s.dur = _perf() - s.t0
+        self._ann.__exit__(None, None, None)
         st = self._stack
         if st and st[-1] is s:
             st.pop()
@@ -113,11 +141,12 @@ class _SpanCtx:
 
 
 class _CycleCtx:
-    __slots__ = ("_root", "_seq")
+    __slots__ = ("_root", "_seq", "_ann")
 
     def __init__(self, root: Span, seq: int):
         self._root = root
         self._seq = seq
+        self._ann = _annotate(root.name)
 
     def __enter__(self):
         return self._root
@@ -126,6 +155,7 @@ class _CycleCtx:
         global _live_cycle
         root = self._root
         root.dur = _perf() - root.t0
+        self._ann.__exit__(None, None, None)
         _tls.stack = None
         if _live_cycle is root:
             _live_cycle = None
@@ -134,11 +164,12 @@ class _CycleCtx:
 
 
 class _AsyncCtx:
-    __slots__ = ("_span", "_seq")
+    __slots__ = ("_span", "_seq", "_ann")
 
     def __init__(self, span: Span, seq: int):
         self._span = span
         self._seq = seq
+        self._ann = _annotate(span.name)
 
     def __enter__(self):
         return self._span
@@ -146,6 +177,7 @@ class _AsyncCtx:
     def __exit__(self, *exc):
         s = self._span
         s.dur = _perf() - s.t0
+        self._ann.__exit__(None, None, None)
         _tls.astack = None
         global _async_count
         with _lock:
@@ -161,11 +193,12 @@ class _AsyncChildCtx:
     span (NOT a new _async root — flush-wide aggregates like summary()'s
     bind_flush_ms sum roots only, so sub-phases never double-count)."""
 
-    __slots__ = ("_span", "_stack")
+    __slots__ = ("_span", "_stack", "_ann")
 
     def __init__(self, span: Span, stack: list):
         self._span = span
         self._stack = stack
+        self._ann = _annotate(span.name)
 
     def __enter__(self):
         return self._span
@@ -173,10 +206,76 @@ class _AsyncChildCtx:
     def __exit__(self, *exc):
         s = self._span
         s.dur = _perf() - s.t0
+        self._ann.__exit__(None, None, None)
         st = self._stack
         if st and st[-1] is s:
             st.pop()
         return False
+
+
+# -- compiles ---------------------------------------------------------------
+
+# JAX's compile events (jax/_src/dispatch.py) -> the stage they time
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_listening = False
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    """JAX's duration listener: fires on the compiling thread as each
+    stage ends."""
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    fun = str(kw.get("fun_name", ""))
+    from ..metrics import metrics as m
+    m.inc(m.JIT_COMPILE_SECONDS, float(duration), stage=stage)
+    if stage == "backend":
+        m.inc(m.JIT_COMPILES, fun=fun)
+    if _enabled:
+        _record_compile(fun, stage, float(duration))
+
+
+def _record_compile(fun: str, stage: str, duration: float) -> None:
+    """A ``compile`` span under the innermost span open on this thread,
+    ending now. The stage's nested compiles ended before it and sit last
+    among the parent's children: they move under it."""
+    stack = getattr(_tls, "stack", None) or getattr(_tls, "astack", None)
+    if not stack:
+        return
+    parent = stack[-1]
+    end = _perf()
+    # the duration is on JAX's wall clock: a start a hair before the
+    # parent's is the two clocks' rounding, not a compile outside it
+    t0 = max(end - duration, parent.t0)
+    s = Span("compile", t0)
+    s.dur = end - t0
+    s.tags = {"fun": fun, "stage": stage}
+    kids = parent.children
+    if kids is None:
+        kids = parent.children = []
+    i = len(kids)
+    while i and kids[i - 1].t0 + kids[i - 1].dur / 2 >= t0:
+        i -= 1
+    if i < len(kids):
+        s.children = kids[i:]
+        del kids[i:]
+    kids.append(s)
+
+
+def _listen() -> None:
+    """Register the compile listener once per process."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _listening = True
+
+
+_listen()
 
 
 # -- control ----------------------------------------------------------------
@@ -377,9 +476,10 @@ def live_phases() -> Dict[str, dict]:
     comes from the ring buffer instead). Top-level child spans of the
     live root, name -> {ms, count, open}; an open span (dur not yet
     written) reports its elapsed wall time so far. Reads deliberately
-    race the recording thread: children lists are append-only and spans
-    are never removed, so a snapshot is always structurally sound —
-    durations of spans closing mid-read may be a frame stale."""
+    race the recording thread: children lists only grow (a ``compile``
+    span may move under the compile that encloses it, never out of the
+    tree), so a snapshot is always structurally sound — durations of
+    spans closing mid-read may be a frame stale."""
     root = _live_cycle
     if root is None:
         return {}
