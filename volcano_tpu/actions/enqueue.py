@@ -25,6 +25,26 @@ class EnqueueAction(Action):
         return "enqueue"
 
     def execute(self, ssn) -> None:
+        """Gate every queue's Pending jobs, in JobOrder within a queue.
+
+        Each queue's pending list is sorted once, as enqueue.go builds one
+        PriorityQueue per queue with JobOrderFn, and then popped from the
+        front. That pops the same sequence as re-sorting the list before
+        every pop, because no job order reads what the gate writes:
+
+        - priority reads the job's priority; gang, ``job.ready()`` from
+          task statuses; sla, creation time plus the waiting-time
+          annotation; tdm, the job's preemptable flag; drf, the job's
+          share, which only allocate events update; the session's
+          fallback, creation time then uid.
+        - the gate writes the podgroup's phase, ``ssn.touched_jobs`` and
+          the inqueue totals of proportion and overcommit.
+
+        The uid breaks every tie, so the order is strict and total: the
+        sorted list is unique, and what remains after its head is popped
+        is still sorted. The queues themselves are re-sorted after every
+        job, so that queues of equal share take turns.
+        """
         queue_list = []
         queue_seen = set()
         jobs_map: Dict[str, List[JobInfo]] = {}
@@ -48,14 +68,16 @@ class EnqueueAction(Action):
 
         inqueued = 0
         with trace.span("enqueue.gate"):
+            # reversed, so that the list's end is the queue's head
+            for jobs in jobs_map.values():
+                jobs.sort(key=job_key, reverse=True)
             while queue_list:
                 queue_list.sort(key=queue_key)
                 queue = queue_list.pop(0)
                 jobs = jobs_map.get(queue.name)
                 if not jobs:
                     continue
-                jobs.sort(key=job_key)
-                job = jobs.pop(0)
+                job = jobs.pop()
 
                 if (job.pod_group.spec.min_resources is None
                         or ssn.job_enqueueable(job)):
