@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 
-def _run_pair(seed, n_tasks=200, n_nodes=60, gang=4):
+def _run_pair(seed, n_tasks=200, n_nodes=60, gang=4, r=4):
     import jax.numpy as jnp
 
     from volcano_tpu.ops.allocate import gang_allocate
@@ -21,7 +21,7 @@ def _run_pair(seed, n_tasks=200, n_nodes=60, gang=4):
     from volcano_tpu.ops.score import ScoreWeights
     from volcano_tpu.utils.synth import synth_arrays
     sa = synth_arrays(n_tasks, n_nodes, gang_size=gang, seed=seed,
-                      utilization=0.4)
+                      utilization=0.4, r=r)
     weights = ScoreWeights.make(sa.group_req.shape[1], binpack=1.0)
     args = [jnp.asarray(a) for a in sa.args] + [weights]
     ref = gang_allocate(*args)
@@ -47,10 +47,20 @@ def _replay_feasible(sa, assign, pipelined):
     return bool(np.all(idle >= tol) and np.all(future >= tol))
 
 
+# r = 4 keeps the ids its cases always had; past eight dimensions the
+# kernel pads the resource axis to 16 sublanes
+CASES = [pytest.param(4, seed, id=str(seed)) for seed in range(4)] + [
+    pytest.param(r, seed, id=f"r{r}-{seed}")
+    for r in (10, 16) for seed in range(4)]
+
+
 class TestPallasEquivalence:
-    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-    def test_ready_kept_and_feasibility(self, seed):
-        sa, (a1, p1, r1, k1), (a2, p2, r2, k2) = _run_pair(seed)
+    @pytest.mark.parametrize("r,seed", CASES)
+    def test_ready_kept_and_feasibility(self, r, seed):
+        sa, (a1, p1, r1, k1), (a2, p2, r2, k2) = _run_pair(seed, r=r)
+        if r > 8:
+            # the gangs ask for the kinds past the eighth
+            assert np.asarray(sa.group_req)[:, 8:].any()
         assert np.array_equal(r1, r2), "ready sets must match"
         assert np.array_equal(k1, k2), "kept sets must match"
         # same number of placements per job
@@ -59,3 +69,11 @@ class TestPallasEquivalence:
             span = tj == j
             assert np.sum(a1[span] >= 0) == np.sum(a2[span] >= 0)
         assert _replay_feasible(sa, a2, p2)
+
+
+@pytest.mark.parametrize("r,pad", [(1, 8), (3, 8), (8, 8), (9, 16),
+                                   (16, 16), (17, 24)])
+def test_resource_pad(r, pad):
+    from volcano_tpu.ops.pallas_allocate import fits_resources, resource_pad
+    assert resource_pad(r) == pad
+    assert fits_resources(r) is (r <= 16)

@@ -83,6 +83,48 @@ def test_pallas_gang_allocate_compiles(one_chip, synth, ns_live):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the dgxmig5k-burst cell's burst call: ~14,000 pending jobs, one group
+# each, over 5,000 nodes (5,120 columns) in ten resource dimensions, so
+# the kernel's resource axis takes 16 sublanes
+WIDE_GROUPS, WIDE_NODES, WIDE_R = 14_336, 5_000, 10
+
+
+def _wide():
+    return synth_arrays(WIDE_GROUPS, WIDE_NODES, gang_size=1, r=WIDE_R,
+                        n_queues=8, seed=25)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    return _wide()
+
+
+def test_pallas_wide_resource_axis_compiles(one_chip, wide):
+    assert pa.resource_pad(WIDE_R) == 16
+    compiled = pa._gang_allocate_pallas_jit.lower(
+        *_pallas_args(wide, one_chip), allow_pipeline=True,
+        ns_live=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_wide_resource_axis_equals_chunked_on_the_chip(wide):
+    """Runs both kernels on one seeded call at the cell's shapes: only
+    where JAX has the chip. Under pytest the suite's conftest holds JAX
+    to the CPU, so on the chip it runs as ``python -m
+    tests.test_tpu_compile``."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs the kernels: needs a TPU backend")
+    from volcano_tpu.ops.allocate import gang_allocate_chunked
+    w = ScoreWeights.make(WIDE_R, binpack=5.0)
+    args = [jax.numpy.asarray(a) for a in wide.args] + [w]
+    want = [np.asarray(x) for x in gang_allocate_chunked(*args)[:4]]
+    got = [np.asarray(x) for x in pa.gang_allocate_pallas(*args)[:4]]
+    assert (got[0] >= 0).sum() > WIDE_GROUPS // 2
+    for name, a, b in zip(("assign", "pipelined", "ready", "kept"),
+                          want, got):
+        assert np.array_equal(a, b), name
+
+
 def test_chunked_kernel_compiles(one_chip, synth):
     from volcano_tpu.ops.allocate import gang_allocate_chunked
     args = _shapes(synth.args, [one_chip] * len(synth.args))
@@ -150,3 +192,10 @@ def test_pallas_smem_budget_brackets_the_compiler(one_chip, n_tasks, fits):
     else:
         with pytest.raises(Exception, match="smem"):
             lowered.compile()
+
+
+if __name__ == "__main__":
+    # on the chip, outside pytest: the R = 10 kernel against the chunked
+    # kernel at the cell's shapes
+    test_pallas_wide_resource_axis_equals_chunked_on_the_chip(_wide())
+    print("pallas (R = 10, 16 sublanes) equals the chunked kernel")

@@ -868,15 +868,17 @@ class BatchSolver:
         namespace-primary pool selection (multi-namespace batches
         included)."""
         from ..ops.allocate import gang_allocate_chunked
-        from ..ops.pallas_allocate import (R_PAD, fits_smem,
-                                           gang_allocate_pallas)
+        from ..ops.pallas_allocate import (R_PAD_MAX, fits_resources,
+                                           fits_smem, gang_allocate_pallas)
         backend = jax.default_backend()
-        pallas_ok = self.rindex.r <= R_PAD and (batch is None or fits_smem(
-            batch.t_pad, batch.j_pad, len(batch.pool_queue), batch.g_pad))
+        pallas_ok = fits_resources(self.rindex.r) and (
+            batch is None or fits_smem(batch.t_pad, batch.j_pad,
+                                       len(batch.pool_queue), batch.g_pad))
         if not pallas_ok and (self.kernel == "pallas" or (
                 self.kernel == "auto" and backend == "tpu")):
-            _log_once("the batch exceeds the Pallas kernel's R_PAD or SMEM "
-                      "budget; an XLA kernel places it")
+            _log_once(f"the batch exceeds the Pallas kernel's {R_PAD_MAX} "
+                      "resource dimensions or its SMEM budget; an XLA "
+                      "kernel places it")
         if self.kernel == "pallas":
             if not pallas_ok:
                 return gang_allocate_chunked, {}
@@ -1159,6 +1161,7 @@ class BatchSolver:
         Returns (assign [T] np, pipelined, ready, kept, served_tier)."""
         from ..metrics import metrics as m
         from ..ops.allocate import gang_allocate_chunked
+        from ..ops.pallas_allocate import resource_pad
 
         reduced_host = None
         reduced_plan = None
@@ -1217,12 +1220,15 @@ class BatchSolver:
 
         kernel_inputs = None
         account_transfer = False
+        n_res = self.rindex.r
         for i, (tier, kfn, kkwargs) in enumerate(eligible):
             span_name = "sharded" if tier == "sharded" else kfn.__name__
+            r_pad = resource_pad(n_res) if tier == "pallas" else n_res
             try:
                 with trace.span("kernel", kernel=span_name,
                                 g_pad=int(batch.g_pad), n_pad=n_axis,
                                 t_pad=int(batch.task_group.shape[0]),
+                                r=n_res, r_pad=r_pad,
                                 pruned=reduced is not None):
                     if tier == "sharded":
                         assign, pipelined, ready, kept = self._run_sharded(

@@ -47,6 +47,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .fdiv import div_rn
 from .score import ScoreWeights, node_score
 
 NEG = -1e30   # plain floats: no backend init at import
@@ -77,12 +78,15 @@ class AllocState(NamedTuple):
 
 def queue_share(q_alloc: jax.Array, q_deserved: jax.Array) -> jax.Array:
     """Dominant share per queue: max_r alloc/deserved with 0/0=0, x/0=1;
-    unbudgeted (+inf deserved) dims contribute 0 (proportion.go:196-209)."""
+    unbudgeted (+inf deserved) dims contribute 0 (proportion.go:196-209).
+    Divided as IEEE divides (``fdiv.div_rn``), so that shares IEEE makes
+    equal tie on the chip too."""
+    finite = ~jnp.isinf(q_deserved) & (q_deserved != 0.0)
     frac = jnp.where(
         jnp.isinf(q_deserved), 0.0,
         jnp.where(q_deserved == 0.0,
                   jnp.where(q_alloc == 0.0, 0.0, 1.0),
-                  q_alloc / jnp.where(q_deserved == 0.0, 1.0, q_deserved)))
+                  div_rn(q_alloc, jnp.where(finite, q_deserved, 1.0))))
     return jnp.max(frac, axis=-1)
 
 
@@ -97,12 +101,13 @@ def namespace_share(ns_alloc: jax.Array, ns_total: jax.Array,
                     ns_weight: jax.Array) -> jax.Array:
     """Weighted dominant share per namespace: max_r alloc/total with
     0/0=0, x/0=1, divided by the namespace weight (drf.py _share_of +
-    namespace_order_fn; reference drf.go:621-646 + namespace ordering)."""
+    namespace_order_fn; reference drf.go:621-646 + namespace ordering),
+    divided as IEEE divides (``fdiv.div_rn``)."""
     frac = jnp.where(ns_total[None, :] > 0.0,
-                     ns_alloc / jnp.where(ns_total[None, :] > 0.0,
-                                          ns_total[None, :], 1.0),
+                     div_rn(ns_alloc, jnp.where(ns_total[None, :] > 0.0,
+                                                ns_total[None, :], 1.0)),
                      jnp.where(ns_alloc == 0.0, 0.0, 1.0))
-    return jnp.max(frac, axis=-1) / ns_weight
+    return div_rn(jnp.max(frac, axis=-1), ns_weight)
 
 
 def make_pool_select(queue_deserved, pool_queue, pool_ns, pool_job_start,

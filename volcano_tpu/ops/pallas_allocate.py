@@ -17,7 +17,9 @@ compiled as ONE kernel with a sequential grid over task steps:
 
 The scoring formula mirrors ops/score.py node_score exactly (binpack /
 least / most / balanced + static bonus), with the resource loop unrolled
-over the padded resource axis (R_PAD=8 sublanes).
+over the real resource dimensions. The resource axis rides on sublanes,
+padded to 8 for up to 8 dimensions and to 16 for up to 16
+(:func:`resource_pad`); a wider cluster goes to an XLA kernel.
 """
 
 from __future__ import annotations
@@ -31,13 +33,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .fdiv import div_rn
 from .score import ScoreWeights
 
 NEG = -1e30
 MASK_THRESH = -1e29      # static rows below this mean "predicate failed"
 BIG = 1e30
-R_PAD = 8                # resource axis padded onto sublanes
 LANE = 128
+R_PAD_MAX = 16           # widest resource axis the kernel holds
+W_RES = 8                # lane of the first per-resource binpack weight
+assert W_RES + R_PAD_MAX <= LANE, "per-resource weights overflow the row"
 # the one-hot matmuls carry queue shares: at the MXU's default precision
 # (bf16 inputs) shares 1e-3 apart tie or swap, and the fair-share order
 # left the XLA kernels' on the chip (exact at HIGHEST)
@@ -63,6 +68,18 @@ def smem_bytes(t_pad: int, j_pad: int, p_pad: int, g_pad: int) -> int:
 
 def fits_smem(t_pad: int, j_pad: int, p_pad: int, g_pad: int) -> bool:
     return smem_bytes(t_pad, j_pad, p_pad, g_pad) <= SMEM_BUDGET_BYTES
+
+
+def resource_pad(r: int) -> int:
+    """Sublanes the kernel gives ``r`` resource dimensions: the next
+    multiple of 8, at least 8, so that a cluster of up to 8 dimensions
+    runs the same program as it always has."""
+    return max(8, -(-r // 8) * 8)
+
+
+def fits_resources(r: int) -> bool:
+    """Whether the kernel holds ``r`` resource dimensions."""
+    return resource_pad(r) <= R_PAD_MAX
 
 
 def _pad_to(x, size, axis, value=0):
@@ -96,7 +113,7 @@ def _kernel(# scalar prefetch (SMEM)
             s_group_bucket,   # [G] i32
             s_pack_milli,     # [G] i32 pack bonus * 1024
             # VMEM inputs
-            group_req_ref,    # [G8, R_PAD] f32
+            group_req_ref,    # [G8, RP] f32 (RP = resource_pad(R))
             qdes_ref,         # [Q8, LANE] f32 (+inf for ungated dims)
             qalloc0_ref,      # [Q8, LANE] f32
             pnjobs_ref,       # [P8, LANE] i32 (lane-broadcast)
@@ -105,9 +122,9 @@ def _kernel(# scalar prefetch (SMEM)
             nsalloc0_ref,     # [NS8, LANE] f32
             nstotal_ref,      # [1, LANE] f32 (first R lanes; 0 elsewhere)
             nsweight_ref,     # [NS8, LANE] f32 (lane-broadcast)
-            idle0_ref,        # [R_PAD, Np] f32
-            future0_ref,      # [R_PAD, Np] f32
-            alloc_ref,        # [R_PAD, Np] f32
+            idle0_ref,        # [RP, Np] f32
+            future0_ref,      # [RP, Np] f32
+            alloc_ref,        # [RP, Np] f32
             ntasks0_ref,      # [1, Np] i32
             maxtasks_ref,     # [1, Np] i32
             eps_ref,          # [1, LANE] f32 (first R lanes)
@@ -116,7 +133,7 @@ def _kernel(# scalar prefetch (SMEM)
             # outputs
             emit_ref,         # [1, 8] i32 SMEM block for this step
             # scratch
-            v_idle, v_future, v_ck_idle, v_ck_future,    # [R_PAD, Np] f32
+            v_idle, v_future, v_ck_idle, v_ck_future,    # [RP, Np] f32
             v_ntasks, v_ck_ntasks,                       # [1, Np] i32
             v_pack,                                      # [1, Np] f32
             v_grow,                                      # [1, Np] f32 group row
@@ -145,10 +162,12 @@ def _kernel(# scalar prefetch (SMEM)
         eps = eps_ref[0:1, :]
         inf_des = des >= BIG
         zero_des = des == 0.0
+        # divided as IEEE divides: shares IEEE makes equal tie on the chip
         frac = jnp.where(
             inf_des, 0.0,
             jnp.where(zero_des, jnp.where(alloc == 0.0, 0.0, 1.0),
-                      alloc / jnp.where(zero_des, 1.0, des)))
+                      div_rn(alloc, jnp.where(zero_des | inf_des, 1.0,
+                                              des))))
         share = jnp.max(frac, axis=1)                       # [Q8]
         over = jnp.any(~((alloc <= des + eps) | inf_des), axis=1)
         # map per-queue share/over onto pools via the one-hot matmul
@@ -170,9 +189,10 @@ def _kernel(# scalar prefetch (SMEM)
             ns_alloc = v_nsalloc[:, :]
             total = nstotal_ref[0:1, :]
             nfrac = jnp.where(total > 0.0,
-                              ns_alloc / jnp.where(total > 0.0, total, 1.0),
+                              div_rn(ns_alloc,
+                                     jnp.where(total > 0.0, total, 1.0)),
                               jnp.where(ns_alloc == 0.0, 0.0, 1.0))
-            ns_key = jnp.max(nfrac, axis=1) / nsweight_ref[:, 0]
+            ns_key = div_rn(jnp.max(nfrac, axis=1), nsweight_ref[:, 0])
         else:
             # Mosaic's iota is integer-only: build it as i32, then cast
             ns_key = jax.lax.broadcasted_iota(
@@ -225,7 +245,7 @@ def _kernel(# scalar prefetch (SMEM)
 
     sc[PREV_G] = g_safe
 
-    req_row = group_req_ref[pl.ds(g_safe, 1), :]            # [1, R_PAD]
+    req_row = group_req_ref[pl.ds(g_safe, 1), :]            # [1, RP]
     static_row = v_grow[0:1, :]                             # [1, Np]
     static_ok = static_row > MASK_THRESH
 
@@ -252,12 +272,15 @@ def _kernel(# scalar prefetch (SMEM)
         fits_future = fits_future & (req_r <= fut_r + eps_r)
         used_r = alloc_r - idle_r
         # binpack (score.py binpack_score)
-        w_r = w_ref[0, 8 + r]
+        w_r = w_ref[0, W_RES + r]
         requested = (req_r > 0) & (w_r > 0)
         denom_ok = alloc_r > 0
         frac = jnp.where(denom_ok,
                          (used_r + req_r) / jnp.maximum(alloc_r, 1e-9), 2.0)
-        per_res = jnp.where(frac <= 1.0, frac * 100.0, 0.0)
+        # a dim that overflows scores 0 (binpack.go: usedFinally >
+        # capacity), tested on the operands: the chip's division can
+        # round a / a above 1
+        per_res = jnp.where(used_r + req_r <= alloc_r, frac * 100.0, 0.0)
         bp_num = bp_num + jnp.where(requested, w_r, 0.0) * per_res
         bp_wsum = bp_wsum + jnp.where(requested, w_r, 0.0)
         if r < 2:
@@ -326,8 +349,8 @@ def _kernel(# scalar prefetch (SMEM)
     new_t_off = t_off + jnp.where(active, 1, 0)
     placed = sc[PLACED] + placed_ok.astype(jnp.int32)
     placed_alloc = sc[PLACED_ALLOC] + take_idle.astype(jnp.int32)
-    # placed_res accumulates on the first R_PAD lanes of a [1, LANE] row
-    req_as_row = jnp.pad(req_row, ((0, 0), (0, LANE - R_PAD)))
+    # placed_res accumulates on the first RP lanes of a [1, LANE] row
+    req_as_row = jnp.pad(req_row, ((0, 0), (0, LANE - req_row.shape[1])))
     v_placedres[:, :] = v_placedres[:, :] + jnp.where(placed_ok, req_as_row, 0.0)
 
     # ---- job boundary: gang commit/rollback + queue charge + next select
@@ -401,7 +424,7 @@ def _pallas_gang_allocate(s_task_group, s_job_start, s_job_ntasks,
     kernel = functools.partial(_kernel, n_res=n_res,
                                allow_pipeline=allow_pipeline,
                                ns_live=ns_live)
-    Np = idle0.shape[1]
+    RP, Np = idle0.shape
     Q8 = qdes.shape[0]
     P8 = pnjobs.shape[0]
     NS8 = nsalloc0.shape[0]
@@ -432,10 +455,10 @@ def _pallas_gang_allocate(s_task_group, s_job_start, s_job_ntasks,
             out_specs=pl.BlockSpec((8, 8), lambda t, *_: (t // 8, 0),
                                    memory_space=pltpu.SMEM),
             scratch_shapes=[
-                pltpu.VMEM((R_PAD, Np), jnp.float32),    # v_idle
-                pltpu.VMEM((R_PAD, Np), jnp.float32),    # v_future
-                pltpu.VMEM((R_PAD, Np), jnp.float32),    # v_ck_idle
-                pltpu.VMEM((R_PAD, Np), jnp.float32),    # v_ck_future
+                pltpu.VMEM((RP, Np), jnp.float32),       # v_idle
+                pltpu.VMEM((RP, Np), jnp.float32),       # v_future
+                pltpu.VMEM((RP, Np), jnp.float32),       # v_ck_idle
+                pltpu.VMEM((RP, Np), jnp.float32),       # v_ck_future
                 pltpu.VMEM((1, Np), jnp.int32),          # v_ntasks
                 pltpu.VMEM((1, Np), jnp.int32),          # v_ck_ntasks
                 pltpu.VMEM((1, Np), jnp.float32),        # v_pack
@@ -540,7 +563,9 @@ def _gang_allocate_pallas_jit(task_group, task_job, task_valid, group_req,
     G = int(group_req.shape[0])
     N = int(node_idle.shape[0])
     R = int(group_req.shape[1])
-    assert R <= R_PAD, f"resource axis {R} exceeds R_PAD={R_PAD}"
+    assert fits_resources(R), \
+        f"resource axis {R} exceeds the kernel's {R_PAD_MAX} sublanes"
+    RP = resource_pad(R)
     Np = ((N + LANE - 1) // LANE) * LANE
     Q = int(queue_deserved.shape[0])
     Q8 = max(8, ((Q + 7) // 8) * 8)
@@ -563,11 +588,11 @@ def _gang_allocate_pallas_jit(task_group, task_job, task_valid, group_req,
     gscore = _pad_to(gscore, Np, axis=1, value=NEG)[:, None, :]
 
     group_req_p = _pad_to(_pad_to(jnp.asarray(group_req, jnp.float32),
-                                  R_PAD, 1), G8, 0)
+                                  RP, 1), G8, 0)
 
-    def tr_nodes(x):   # [N, R] -> [R_PAD, Np]
+    def tr_nodes(x):   # [N, R] -> [RP, Np]
         x = jnp.asarray(x, jnp.float32)
-        return _pad_to(_pad_to(x, R_PAD, 1).T, Np, 1)
+        return _pad_to(_pad_to(x, RP, 1).T, Np, 1)
 
     def row_nodes(x, dtype=jnp.int32):   # [N] -> [1, Np]
         return _pad_to(jnp.asarray(x, dtype)[None, :], Np, 1)
@@ -608,7 +633,7 @@ def _gang_allocate_pallas_jit(task_group, task_job, task_valid, group_req,
     w_row = w_row.at[0, 2].set(weights.most)
     w_row = w_row.at[0, 3].set(weights.balanced)
     w_row = jax.lax.dynamic_update_slice(
-        w_row, _pad_to(weights.binpack_res[None, :], R_PAD, 1), (0, 8))
+        w_row, _pad_to(weights.binpack_res[None, :], RP, 1), (0, W_RES))
 
     emits = _pallas_gang_allocate(
         s_task_group,

@@ -68,10 +68,12 @@ def binpack_score(req, used, alloc, w_res, xp=jnp):
     """
     requested = (req > 0) & (w_res > 0)
     denom_ok = alloc > 0
-    frac = xp.where(denom_ok, (used + req[None, :]) / xp.maximum(alloc, 1e-9), 2.0)
+    used_final = used + req[None, :]
+    frac = xp.where(denom_ok, used_final / xp.maximum(alloc, 1e-9), 2.0)
     # nodes where a requested dim overflows alloc contribute 0 (binpack
-    # returns 0 when usedFinally > allocatable)
-    per_res = xp.where(frac <= 1.0, frac * 100.0, 0.0)        # [N, R]
+    # returns 0 when usedFinally > allocatable), tested on the operands:
+    # the chip's division can round a / a above 1
+    per_res = xp.where(used_final <= alloc, frac * 100.0, 0.0)  # [N, R]
     w = xp.where(requested, w_res, 0.0)[None, :]               # [1, R]
     wsum = xp.maximum(xp.sum(xp.where(requested, w_res, 0.0)), 1e-9)
     return xp.sum(per_res * w, axis=-1) / wsum                 # [N]

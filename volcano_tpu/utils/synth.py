@@ -84,9 +84,10 @@ def synth_arrays(n_tasks: int, n_nodes: int, *, gang_size: int = 8,
 
     Nodes: 64-core/256GiB-shaped with uniform random pre-existing usage around
     ``utilization``; resource dims are [cpu(milli), memory(MiB), pods-slack,
-    accelerator]. Tasks: gangs of ``gang_size`` with per-gang resource shapes;
-    each gang is one group (homogeneous replicas). Rack-affinity static score
-    prefers a random rack per gang (config-5's topology-aware nodeorder).
+    accelerator], then ``r - 4`` further scalar kinds. Tasks: gangs of
+    ``gang_size`` with per-gang resource shapes; each gang is one group
+    (homogeneous replicas). Rack-affinity static score prefers a random
+    rack per gang (config-5's topology-aware nodeorder).
     """
     rng = np.random.default_rng(seed)
     n_jobs = max(1, n_tasks // gang_size)
@@ -104,6 +105,10 @@ def synth_arrays(n_tasks: int, n_nodes: int, *, gang_size: int = 8,
     cap[:n_nodes, 1] = 256 * 1024.0                       # 256 GiB in MiB
     cap[:n_nodes, 2] = 110.0                              # pods dimension
     cap[:n_nodes, 3] = 8.0                                # accelerators
+    if r > 4:
+        # further scalar kinds (MIG slices, hugepages, ...): each node
+        # holds 0, 2 or 4 of each
+        cap[:n_nodes, 4:] = rng.choice([0.0, 2.0, 4.0], (n_nodes, r - 4))
     used_frac = rng.uniform(0.0, 2 * utilization, (n_pad, 1)).astype(np.float32)
     used = (cap * used_frac).astype(np.float32)
     idle = cap - used
@@ -117,6 +122,11 @@ def synth_arrays(n_tasks: int, n_nodes: int, *, gang_size: int = 8,
     group_req[:n_groups, 1] = rng.choice([2048, 4096, 8192, 16384], n_groups)
     group_req[:n_groups, 2] = 1.0
     group_req[:n_groups, 3] = rng.choice([0, 0, 0, 1], n_groups)
+    if r > 4:
+        # half the gangs ask one unit of one further kind
+        kind = rng.integers(4, r, n_groups)
+        asks = np.flatnonzero(rng.random(n_groups) < 0.5)
+        group_req[asks, kind[asks]] = 1.0
 
     task_group = np.zeros(t_pad, np.int32)
     task_job = np.full(t_pad, n_jobs, np.int32)           # sentinel fill
@@ -197,7 +207,7 @@ def synth_arrays(n_tasks: int, n_nodes: int, *, gang_size: int = 8,
         group_static_score[:n_groups, :n_nodes] = (
             (gang_rack[:, None] == node_rack[None, :]) * 50.0)
 
-    eps = np.array([100.0, 0.1, 0.1, 0.1], np.float32)[:r]
+    eps = np.array([100.0] + [0.1] * (r - 1), np.float32)
 
     return SynthArrays(
         task_group=task_group, task_job=task_job, task_valid=task_valid,
