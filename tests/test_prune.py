@@ -88,6 +88,29 @@ def run_cluster(build, conf):
     return h
 
 
+def walk(span):
+    yield span
+    for c in span.children or ():
+        yield from walk(c)
+
+
+def run_traced(h):
+    """One enqueue + allocate cycle under the flight recorder; returns
+    every span of the cycle."""
+    from volcano_tpu.trace import tracer
+    h.open_session()
+    tracer.enable()
+    try:
+        with tracer.cycle():
+            h.run_actions("enqueue", "allocate")
+        root = tracer.last_record().root
+    finally:
+        tracer.disable()
+        tracer.reset()
+    h.close_session()
+    return list(walk(root))
+
+
 def fallback_totals():
     from volcano_tpu.ops.prune import FALLBACK_REASONS
     return {r: m.counter_total(m.PRUNE_FALLBACK, reason=r)
@@ -262,25 +285,8 @@ class TestLossGuard:
         """A traced cycle says which guard sent the call to full width:
         the open ``solver.place`` span carries the reason and the pairs
         behind it."""
-        from volcano_tpu.trace import tracer
         h = skewed_cluster(Harness(LOW_COVERAGE_CONF))
-        h.open_session()
-        tracer.enable()
-        try:
-            with tracer.cycle():
-                h.run_actions("enqueue", "allocate")
-            root = tracer.last_record().root
-        finally:
-            tracer.disable()
-            tracer.reset()
-        h.close_session()
-
-        def walk(s):
-            yield s
-            for c in s.children or ():
-                yield from walk(c)
-
-        places = [s for s in walk(root) if s.name == "solver.place"]
+        places = [s for s in run_traced(h) if s.name == "solver.place"]
         assert places
         assert places[0].tags["prune_fallback"] == "low_coverage"
         assert places[0].tags["fallback_pairs"] >= 1
@@ -487,3 +493,134 @@ class TestCoverageKs:
         finally:
             ex.disable()
             ex.reset()
+
+
+# ---------------------------------------------------------------------------
+# auto mode on the compiled single-chip Pallas tier
+# ---------------------------------------------------------------------------
+
+
+def auto_fleet(h, busy=False):
+    """The auto floor's 4,096 nodes and eight pending gangs of 4; with
+    ``busy`` a running pod on every third node, whose fill spreads the
+    session-open scores so far that a top-k shortlist trips the
+    ``low_coverage`` guard (as the burst cells' resident burst does)."""
+    h.add("queues", build_queue("default", weight=1))
+    for i in range(4096):
+        h.add("nodes", build_node(f"node-{i}",
+                                  {"cpu": "16", "memory": "32Gi"}))
+    for i in range(0, 4096, 3) if busy else ():
+        h.add("podgroups", build_pod_group(
+            f"fill-{i}", "ns1", "default", 1, phase="Running"))
+        h.add("pods", build_pod(
+            "ns1", f"fill-{i}", f"node-{i}", "Running",
+            {"cpu": "4", "memory": "1Gi"}, f"fill-{i}"))
+    for j in range(8):
+        h.add("podgroups", build_pod_group(f"pg-{j}", "ns1", "default", 4,
+                                           phase="Inqueue"))
+        for t in range(4):
+            h.add("pods", build_pod("ns1", f"p{j}-{t}", "", "Pending",
+                                    {"cpu": "2", "memory": "4Gi"},
+                                    f"pg-{j}"))
+    return h
+
+
+def select_compiled_pallas(monkeypatch):
+    """Make every batch select the compiled (not interpreted) Pallas
+    kernel of one chip. The CPU cannot run Mosaic, so the kernel under
+    that name is the chunked kernel, which places identically."""
+    from volcano_tpu.framework.solver import BatchSolver
+    from volcano_tpu.ops.allocate import gang_allocate_chunked
+
+    def gang_allocate_pallas(*args, **kwargs):
+        return gang_allocate_chunked(*args, **kwargs)
+
+    monkeypatch.setattr(BatchSolver, "_select_kernel",
+                        lambda self, batch=None: (gang_allocate_pallas, {}))
+
+
+def tap_node_axes(monkeypatch):
+    """Record the node axis each ``_execute_ladder`` call hands the
+    kernel, beside the full padded width."""
+    from volcano_tpu.framework.solver import BatchSolver
+    real = BatchSolver._execute_ladder
+    axes = []
+
+    def tapped(self, batch, narr, *args, reduced=None, **kwargs):
+        axes.append((reduced.u_pad if reduced is not None
+                     else int(narr.idle.shape[0]), int(narr.n_pad)))
+        return real(self, batch, narr, *args, reduced=reduced, **kwargs)
+
+    monkeypatch.setattr(BatchSolver, "_execute_ladder", tapped)
+    return axes
+
+
+def skipped_total():
+    return m.counter_total(m.PRUNE_SKIPPED, reason="pallas_full_width")
+
+
+class TestAutoOnCompiledPallas:
+    # (tier, prune.enable, distils): auto skips pruning only where the
+    # full-width ladder is the compiled single-chip Pallas kernel
+    @pytest.mark.parametrize("tier,mode,distils", [
+        ("pallas", "auto", False),
+        ("pallas", "true", True),
+        ("chunked", "auto", True),
+        ("native", "auto", True),
+        ("mesh", "auto", True),
+    ])
+    def test_auto_prunes_off_the_compiled_pallas_tier(
+            self, monkeypatch, tier, mode, distils):
+        """On the compiled Pallas tier `auto` runs no distillation, counts
+        the skip on volcano_prune_skipped_total and tags the place span,
+        and places what `prune.enable: off` places; forced pruning and
+        every other tier (chunked, native, the mesh) still distil."""
+        args = {"mesh.enable": "true" if tier == "mesh" else "false"}
+        if tier == "pallas":
+            select_compiled_pallas(monkeypatch)
+        elif tier != "mesh":
+            args["kernel"] = tier
+        s0 = skipped_total()
+        h = auto_fleet(Harness(conf_with_solver(
+            **args, **{"prune.enable": mode})))
+        spans = run_traced(h)
+        distilled = [s for s in spans if s.name == "prune_distill"]
+        place = next(s for s in spans if s.name == "solver.place")
+        if distils:
+            assert distilled
+            assert skipped_total() == s0
+            assert "prune_skipped" not in (place.tags or {})
+            return
+        assert not distilled
+        assert skipped_total() == s0 + 1
+        assert place.tags["prune_skipped"] == "pallas_full_width"
+        kernels = [s for s in spans if s.name == "kernel"]
+        assert kernels and not any(s.tags["pruned"] for s in kernels)
+        dense = run_cluster(auto_fleet, conf_with_solver(
+            **args, **{"prune.enable": "off"}))
+        assert h.binds == dense.binds
+        assert len(h.binds) == 32
+
+    def test_pallas_node_axis_does_not_follow_occupancy(self, monkeypatch):
+        """The burst cells' warm-up places on an empty fleet and their
+        window on one a third busy. Pruned, the first call runs on a
+        shortlist union and the second trips `low_coverage` to full
+        width: two node axes, so two Pallas programs. Under `auto` on the
+        compiled Pallas tier both calls get the full padded axis."""
+        select_compiled_pallas(monkeypatch)
+        axes = tap_node_axes(monkeypatch)
+        for busy in (False, True):
+            run_cluster(lambda h: auto_fleet(h, busy),
+                        conf_with_solver(**{"mesh.enable": "false"}))
+        assert len(axes) == 2
+        assert all(n_axis == n_pad for n_axis, n_pad in axes)
+        assert axes[0] == axes[1]
+        # the same two calls pruned: the axis moves with occupancy
+        axes.clear()
+        f0 = fallback_totals()
+        for busy in (False, True):
+            run_cluster(lambda h: auto_fleet(h, busy), conf_with_solver(
+                **{"mesh.enable": "false", "prune.enable": "true"}))
+        assert fallback_totals()["low_coverage"] == f0["low_coverage"] + 1
+        assert axes[0][0] < axes[0][1]
+        assert axes[-1][0] == axes[-1][1]

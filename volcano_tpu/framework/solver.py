@@ -345,7 +345,9 @@ class BatchSolver:
         # (ops/prune.py, docs/design/pruning.md): per-gang top-k node
         # shortlists distilled from the compiled [G, N] mask/score
         # tensors shrink the kernel's node axis to the shortlist union;
-        # `prune.enable: off` restores the exact unpruned path.
+        # `auto` leaves the compiled one-chip Pallas tier at full width,
+        # `true` prunes on every tier, and `prune.enable: off` restores
+        # the exact unpruned path.
         #   configurations:
         #   - name: solver
         #     arguments: {prune.enable: "auto"|"true"|"off",
@@ -915,6 +917,16 @@ class BatchSolver:
             return gang_allocate_chunked, {}
         return gang_allocate, {}
 
+    def _compiled_pallas_tier(self, batch: TaskBatch, slot_kwargs) -> bool:
+        """Does the full-width ladder run ``batch`` on the compiled Pallas
+        kernel of one chip: no mesh, no constraint slots (those run the
+        chunked kernel) and the kernel not interpreted?"""
+        if self.mesh is not None or slot_kwargs:
+            return False
+        kernel_fn, kernel_kwargs = self._select_kernel(batch)
+        return kernel_fn.__name__ == "gang_allocate_pallas" and \
+            not kernel_kwargs.get("interpret", False)
+
     def place(self, ordered_jobs: List[Tuple[JobInfo, List[TaskInfo]]],
               allow_pipeline: bool = True) -> PlacementResult:
         """Run the gang-allocate kernel for the ordered job/task batch against
@@ -1016,10 +1028,20 @@ class BatchSolver:
         t_kernel = time.perf_counter()
         out = None
         if self.prune.active(n_real_nodes):
-            out = self._place_pruned(
-                batch, narr, gmask, static_score, task_bucket, pack_bonus,
-                q_deserved, q_alloc0, ns_weight, ns_alloc0, ns_total,
-                ns_live, eps, allow_pipeline, slot_kwargs)
+            if not self.prune.forced and \
+                    self._compiled_pallas_tier(batch, slot_kwargs):
+                # the Pallas program specialises on the node axis, so
+                # every union width (and the guard's full width) is a
+                # compile of seconds, against at most the full-width
+                # kernel's device time saved: auto sends the batch
+                # straight to full width
+                m.inc(m.PRUNE_SKIPPED, reason="pallas_full_width")
+                trace.add_tags(prune_skipped="pallas_full_width")
+            else:
+                out = self._place_pruned(
+                    batch, narr, gmask, static_score, task_bucket,
+                    pack_bonus, q_deserved, q_alloc0, ns_weight, ns_alloc0,
+                    ns_total, ns_live, eps, allow_pipeline, slot_kwargs)
         if out is None:
             out = self._execute_ladder(
                 batch, narr, gmask, static_score, task_bucket, pack_bonus,

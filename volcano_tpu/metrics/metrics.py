@@ -170,10 +170,14 @@ PADDED_WASTE = f"{NS}_padded_waste_ratio"
 # (level="single"|"two_level"), fallbacks to the full-width kernel by
 # reason (reason="low_coverage"|"shortlist_exhausted"|"wide_union"|
 # "empty_union"|"crash" — the loss-guard contract: pruning never loses
-# a placement the dense kernel would have made), and the width of the
-# last reduced node axis (the union of every gang's shortlist)
+# a placement the dense kernel would have made), place() calls that
+# `prune.enable: auto` would have pruned but sent straight to full width
+# (reason="pallas_full_width": the compiled single-chip Pallas tier,
+# where each reduced width is a program of its own), and the width of
+# the last reduced node axis (the union of every gang's shortlist)
 PRUNE_RUNS = f"{NS}_prune_runs_total"
 PRUNE_FALLBACK = f"{NS}_prune_fallback_total"
+PRUNE_SKIPPED = f"{NS}_prune_skipped_total"
 PRUNE_UNION_WIDTH = f"{NS}_prune_union_width"
 # federated control plane (docs/design/federation.md): journal frames /
 # events replicated leader->follower, contiguity gaps detected at the

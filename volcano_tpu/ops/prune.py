@@ -86,8 +86,11 @@ class PruneConf:
     """The ``solver`` conf's ``prune.*`` arguments.
 
     ``prune.enable`` "auto" (default) engages above ``prune.min_nodes``
-    ready nodes; "true" forces it at any scale; "false"/"off" restores
-    the exact unpruned path (distillation never runs).
+    ready nodes, except where the batch's tier is the compiled
+    single-chip Pallas kernel (the solver sends those to full width:
+    each reduced width would be a program of its own); "true" forces it
+    at any scale and on every tier; "false"/"off" restores the exact
+    unpruned path (distillation never runs).
     ``prune.demand_aware`` (default on) widens a shortlist past
     ``prune.k`` when the tasks that will drain it need more capacity
     than k nodes can hold — a 500k-task uniform batch drains far more
@@ -134,14 +137,16 @@ class PruneConf:
     def off(self) -> bool:
         return self.mode in ("off", "false", "0", "no")
 
+    @property
+    def forced(self) -> bool:
+        return self.mode in ("true", "1", "yes", "on")
+
     def active(self, n_nodes: int) -> bool:
         """Does pruning engage for a place() over ``n_nodes`` ready
         nodes? Force ("true") still needs a node to prune toward."""
         if self.off or n_nodes <= 0:
             return False
-        if self.mode in ("true", "1", "yes", "on"):
-            return True
-        return n_nodes >= self.min_nodes
+        return self.forced or n_nodes >= self.min_nodes
 
 
 class PruneContext:
