@@ -2,7 +2,7 @@
 
 PYTHON ?= python
 
-.PHONY: test unit-test e2e bench bench-all bench-check multichip-dryrun \
+.PHONY: test unit-test e2e multichip-dryrun \
 	deploy deploy-up trace-smoke sim-smoke flush-bench chaos-smoke \
 	failover-smoke obs-smoke incr-smoke multichip-smoke constraint-smoke \
 	storm-smoke explain-smoke prune-smoke federation-smoke \
@@ -45,14 +45,6 @@ unit-test: lint
 # the multi-process control-plane e2e alone (four OS processes)
 e2e:
 	$(PYTHON) -m pytest tests/test_multiprocess.py tests/test_e2e_sim.py -q
-
-# headline benchmark (one JSON line; TPU when available)
-bench:
-	$(PYTHON) bench.py
-
-# the five BASELINE.md configs + full-cycle runOnce -> BENCH_DETAILS.json
-bench-all:
-	$(PYTHON) bench.py --all
 
 # flight-recorder smoke gate: one small traced cycle, /debug/trace +
 # /debug/pending fetched over HTTP and validated against the span schema,
@@ -123,22 +115,6 @@ obs-smoke: failover-smoke
 # the incremental/quiet fast paths demonstrably engaged.
 incr-smoke: obs-smoke
 	JAX_PLATFORMS=cpu $(PYTHON) -m volcano_tpu.sim.cli incr
-
-# bench regression gate: compare the fresh BENCH_r10.json row (written
-# by `make bench`) against the BENCH_r09 baseline with machine-
-# calibration scaling (this box drifts up to ~2.3x across captures).
-# When the fresh row carries the 10x metric (500k x 50k, round 9) the
-# gate switches to the 10x mode: kernel budget task-linear off the
-# same-capture sharded anchor, incremental-steady budget off the
-# absolute 20 ms r05-machine target with a shape-linear ceiling,
-# sharded-tier proof + flush-residue lines required
-# (docs/design/sharded_kernel.md). Same-metric rows keep the full
-# r08-era key-for-key gate. Round 10 additionally requires the
-# constraint columns: constrained 50k x 10k kernel <= 1.5x the
-# unconstrained one, victim-selection kernel faster than the Python
-# walk (docs/design/constraints.md).
-bench-check:
-	JAX_PLATFORMS=cpu $(PYTHON) tools/bench_check.py
 
 # multi-chip sharded-default gate (docs/design/sharded_kernel.md),
 # after incr-smoke: the same seeded 200-tick churn (flaps, gang pod

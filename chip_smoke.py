@@ -310,11 +310,68 @@ def phase_allocate(n_nodes: int = N_NODES, n_gangs: int = N_GANGS,
 
 # -- phase 2: preempt -------------------------------------------------------
 
+# preempt over a vectorizable plugin chain: every enabled preemptable
+# plugin has a compiled form, so the victim kernel (ops/victims.py) serves
+# it unless `victims.kernel: "off"` forces the Python walk
+CONF_VICTIMS = """
+actions: "preempt"
+tiers:
+- plugins:
+  - name: priority
+  - name: conformance
+  - name: gang
+- plugins:
+  - name: predicates
+  - name: nodeorder
+"""
+
+
+def victim_env(conf_src: str, vn_nodes: int, n_low: int, n_high: int):
+    """Preemption under pressure: ``n_low`` low-priority gangs of 8
+    (minAvailable 4) fill every node, and ``n_high`` high-priority gangs
+    of 8 wait for room. Returns (store, cache, conf)."""
+    from volcano_tpu.apiserver import ObjectStore
+    from volcano_tpu.cache import SchedulerCache
+    from volcano_tpu.framework import parse_scheduler_conf
+    from volcano_tpu.models.objects import ObjectMeta, PriorityClass
+    from volcano_tpu.utils.test_utils import (FakeBinder, FakeEvictor,
+                                              build_node, build_pod,
+                                              build_pod_group, build_queue)
+    store = ObjectStore()
+    cache = SchedulerCache(store, binder=FakeBinder(store),
+                           evictor=FakeEvictor(store))
+    cache.run()
+    store.create("queues", build_queue("default", weight=1))
+    store.create("priorityclasses", PriorityClass(
+        metadata=ObjectMeta(name="high"), value=100))
+    store.create("priorityclasses", PriorityClass(
+        metadata=ObjectMeta(name="low"), value=1))
+    for i in range(vn_nodes):
+        store.create("nodes", build_node(
+            f"node-{i}", {"cpu": "16", "memory": "32Gi"}))
+    for j in range(n_low):
+        store.create("podgroups", build_pod_group(
+            f"lo-{j}", "ns1", "default", 4, phase="Running",
+            priority_class="low"))
+        for t in range(8):
+            store.create("pods", build_pod(
+                "ns1", f"lo-{j}-{t}", f"node-{(j * 8 + t) % vn_nodes}",
+                "Running", {"cpu": "14", "memory": "28Gi"}, f"lo-{j}"))
+    for j in range(n_high):
+        store.create("podgroups", build_pod_group(
+            f"hi-{j}", "ns1", "default", 8, phase="Inqueue",
+            priority_class="high"))
+        for t in range(8):
+            store.create("pods", build_pod(
+                "ns1", f"hi-{j}-{t}", "", "Pending",
+                {"cpu": "14", "memory": "28Gi"}, f"hi-{j}"))
+    return store, cache, parse_scheduler_conf(conf_src)
+
+
 def preempt_evictions(conf_src: str, vn_nodes: int, n_low: int,
                       n_high: int, tag: str):
-    from volcano_tpu.bench_suite import victim_env
     from volcano_tpu.framework import close_session, get_action, open_session
-    store, cache, _, conf = victim_env(conf_src, vn_nodes, n_low, n_high)
+    store, cache, conf = victim_env(conf_src, vn_nodes, n_low, n_high)
     try:
         c0 = counters()
         ssn = open_session(cache, conf.tiers, conf.configurations)
@@ -335,7 +392,6 @@ def preempt_evictions(conf_src: str, vn_nodes: int, n_low: int,
 
 def phase_preempt(vn_nodes: int = 2000, n_low: int = 250,
                   n_high: int = 125) -> dict:
-    from volcano_tpu.bench_suite import CONF_VICTIMS
     log(f"phase preempt: {n_high * GANG} high-priority tasks x "
         f"{vn_nodes} nodes full of low-priority gangs")
     evicts, ms, d = preempt_evictions(CONF_VICTIMS, vn_nodes, n_low,

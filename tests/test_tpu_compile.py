@@ -134,7 +134,7 @@ def test_chunked_kernel_compiles(one_chip, synth):
 
 
 def test_victim_kernel_compiles(one_chip):
-    """bench_suite.victim_env's widths: 1,000 preemptors over 2,000
+    """chip_smoke.victim_env's widths: 1,000 preemptors over 2,000
     nodes, eight victims a node."""
     from volcano_tpu.ops.victims import victim_prefix_batch
     b, n, v, r = 1000, 2000, 8, 4

@@ -9,7 +9,7 @@ the full 50k x 10k regime so the commit path can be measured standalone
 (``python tools/flush_bench.py --tasks 50000 --nodes 10000``), and
 ``--profile`` wraps the flush in cProfile and prints the top cumulative
 entries — the fastest way to see where the remaining flush wall-clock
-lives without paying a full `python bench.py` cycle.
+lives without paying a full scheduling cycle.
 
 Runs the identical burst TWICE on fresh envs and fails (exit 1) unless
 the two runs are bit-identical — same journal (rv, action, key,

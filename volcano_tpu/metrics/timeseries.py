@@ -6,8 +6,7 @@ last few minutes. ``sample()`` — called once per scheduling cycle from
 ``Scheduler.run_once`` while tracing is enabled — snapshots a fixed
 whitelist of counters/gauges plus caller-supplied extras (cycle wall
 time, cycle seq) into a bounded ring served at ``/debug/timeseries``,
-written into sim repro bundles (``timeseries.json``) and attached to
-``bench.py``'s JSON row.
+and written into sim repro bundles (``timeseries.json``).
 
 Sizing: ``CAPACITY`` = 512 samples. At the production 1 s schedule
 period that is ~8.5 minutes of history; one sample is a flat dict of a

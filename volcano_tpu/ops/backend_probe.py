@@ -1,7 +1,7 @@
 """Instrumented, timeout-bounded accelerator backend-init probe.
 
-bench.py's parent process never imports JAX (a chip belongs to one
-process at a time), so it asks this probe whether the machine has a TPU
+A parent process that must stay off JAX (a chip belongs to one
+process at a time) can ask this probe whether the machine has a TPU
 that answers. The probe runs in a child process that emits one JSON line
 per init phase —
 
@@ -14,8 +14,7 @@ per init phase —
 (``last_phase`` is the last phase that COMPLETED). The parent runs the
 child under a hard timeout and kill, records
 ``volcano_backend_probe_total{outcome="alive"|"dead"|"hang"}``, and
-returns a structured verdict dict that bench.py logs and embeds in its
-JSON row. Where the libtpu plug-in is installed but no TPU device node
+returns a structured verdict dict for the caller to log. Where the libtpu plug-in is installed but no TPU device node
 exists (a CPU-only machine with the same installation, such as a
 development sandbox), the verdict is ``dead`` with a named
 ``root_cause`` in about a second, without attempting the init
@@ -229,9 +228,9 @@ def main(argv=None) -> int:
         timeout = float(argv[argv.index("--timeout") + 1])
     verdict = run_probe(timeout_s=timeout,
                         log=lambda s: print(s, file=sys.stderr))
-    # ONE compact line: callers that subprocess this module (bench.py's
-    # parent keeps jax — and therefore this package — out of its own
-    # process) parse stdout's last line
+    # ONE compact line: callers that subprocess this module (to keep
+    # jax — and therefore this package — out of their own process)
+    # parse stdout's last line
     print(json.dumps(verdict))
     return 0 if verdict["alive"] else 1
 

@@ -1,12 +1,12 @@
 """Candidate pruning + two-level hierarchical placement (the kernel
 scale wall, docs/design/pruning.md).
 
-BENCH_r12's loudest number: at 500k x 50k the sharded kernel is 624.7 s
-of a 637.5 s cycle, and the cost is the dense [G, N] tasks x nodes
-product itself — every scan step sweeps the whole node axis. This
-module shrinks the problem BEFORE the kernel runs, following the
-packing-and-placement structure of arxiv 2004.00518 and Tesserae's
-scalable-policy framing (arxiv 2508.04953):
+At 500k x 50k the sharded kernel once took 624.7 s of a 637.5 s CPU
+cycle, and the cost is the dense [G, N] tasks x nodes product itself —
+every scan step sweeps the whole node axis. This module shrinks the
+problem BEFORE the kernel runs, following the packing-and-placement
+structure of arxiv 2004.00518 and Tesserae's scalable-policy framing
+(arxiv 2508.04953):
 
 * **Shortlist distillation** — per gang (per (gang, topology-domain)
   pair when the constraint compiler's slot tensors are live), the top-k

@@ -33,8 +33,8 @@ acceptance depends on a running cluster-wide simulation that has no
 closed per-victim form.
 
 The jnp forms (``victim_prefix_batch`` / ``reclaim_prefix_batch``) vmap
-the prefix kernels over a preemptor batch for the one-shot task x node
-bench (tools/victim_bench paths in bench.py); the in-action integration
+the prefix kernels over a preemptor batch for a one-shot task x node
+pass; the in-action integration
 uses the numpy twins — the action applies evictions between preemptors,
 so batching across preemptors would change semantics.
 """

@@ -4,8 +4,8 @@ under a virtual clock, audits invariants after every tick, and shrinks
 any failure to a deterministic ``{seed, tick}`` repro.
 
 See docs/design/simulation.md for the event model, invariant catalog and
-repro-bundle format; ``vcctl sim run|smoke|replay`` and ``bench.py --sim``
-are the entry points.
+repro-bundle format; ``vcctl sim run|smoke|replay`` and
+``python -m volcano_tpu.sim.cli`` are the entry points.
 
 Attribute access is lazy (PEP 562): ``vcctl`` registers the ``sim``
 argparse group on every invocation, and importing the engine eagerly

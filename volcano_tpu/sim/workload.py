@@ -117,8 +117,8 @@ def resident_backlog(n_jobs: int, gang: int, cpu: str = "2",
                      duration_s: float = 1e9,
                      name_prefix: str = "rj",
                      min_available: int = 0) -> List[Event]:
-    """A cold backlog: ``n_jobs`` gangs all arriving at t=0 (the sim's
-    analogue of bench.py's one-shot populate; near-infinite duration keeps
+    """A cold backlog: ``n_jobs`` gangs all arriving at t=0 (a one-shot
+    populate; near-infinite duration keeps
     them resident unless faults kill them). ``min_available`` below the
     gang size makes the residents elastic — preemptable down to min."""
     return [make_event(0.0, "job_arrival", name=f"{name_prefix}-{j}",

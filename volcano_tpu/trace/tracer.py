@@ -63,7 +63,7 @@ _ASYNC_SPAN_CAP = 4096
 _seq = 0            # sequence of the cycle currently (or last) recording
 _tls = threading.local()
 
-# per-phase wall budgets in ms (docs/design/perf.md's budget rows); a
+# per-phase wall budgets in ms (the reference's 1 s schedule period); a
 # cycle whose phase exceeds its budget is flagged in the summary and
 # counted in volcano_trace_phase_over_budget_total
 _budgets: Dict[str, float] = {}
@@ -563,8 +563,7 @@ def chrome_trace(rec: CycleRecord) -> dict:
 
 def flat_phases(rec: CycleRecord) -> Dict[str, dict]:
     """'/'-joined span paths -> {ms, count}, aggregated over the tree
-    (the per-phase breakdown behind bench.py --trace and the phase-timer
-    table)."""
+    (the per-phase breakdown behind chip_smoke.py's top_phases)."""
     out: Dict[str, dict] = {}
 
     def walk(s: Span, prefix: str) -> None:
@@ -579,30 +578,6 @@ def flat_phases(rec: CycleRecord) -> Dict[str, dict]:
 
     for c in rec.root.children or ():
         walk(c, "")
-    for e in out.values():
-        e["ms"] = round(e["ms"], 3)
-    return out
-
-
-def async_phases(rec: CycleRecord) -> Dict[str, dict]:
-    """'/'-joined span paths -> {ms, count} over the cycle's ASYNC spans
-    (the bind flush that follows it): the flat_phases twin for the
-    executor side, behind bench.py's flush sub-phase attribution
-    (bind_flush.apply / bind_flush.store / bind_flush.store/bind_flush.echo)."""
-    out: Dict[str, dict] = {}
-
-    def walk(s: Span, prefix: str) -> None:
-        path = f"{prefix}/{s.name}" if prefix else s.name
-        e = out.get(path)
-        if e is None:
-            out[path] = e = {"ms": 0.0, "count": 0}
-        e["ms"] += s.dur * 1000.0
-        e["count"] += 1
-        for c in s.children or ():
-            walk(c, path)
-
-    for s in _async_spans_for(rec.seq):
-        walk(s, "")
     for e in out.values():
         e["ms"] = round(e["ms"], 3)
     return out

@@ -1,5 +1,5 @@
 """JAX's persistent compilation cache for the entry points that compile
-(chip_smoke.py, cmd/scheduler.py, bench.py's workers).
+(chip_smoke.py, cmd/scheduler.py, the benchmark harness).
 
 The directory is part of every cache key, so it must be stable across
 runs: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself and
